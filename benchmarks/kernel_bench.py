@@ -1,5 +1,7 @@
-"""render_score Pallas kernel vs jnp reference (interpret mode on CPU —
-correctness-grade timing; on TPU flip ops.DEFAULT_INTERPRET)."""
+"""Pallas kernels vs their jnp references. The kernels run in the
+platform's mode (``repro.kernels.default_interpret``): on the CPU that is
+the Pallas interpreter, so those rows are correctness-grade timings and
+carry ``_pallas_interpret`` in their names."""
 
 from __future__ import annotations
 
@@ -7,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import handmodel, objective
+from repro import kernels
 from repro.core.camera import Camera
 from repro.kernels import ops, ref
 
@@ -25,6 +28,8 @@ def bench() -> list:
     d_o = objective.render_depth(hs[0], cam).reshape(-1)
     mask = d_o < 5.0
 
+    interp = kernels.default_interpret()
+    mode = "pallas_interpret" if interp else "pallas"
     rows = []
     work = n * rays.shape[0] * handmodel.NUM_SPHERES
     t_ref = time_fn(
@@ -39,9 +44,9 @@ def bench() -> list:
         jax.jit(lambda s: ops.render_score(s, rays, d_o, mask)), spheres
     )
     rows.append((
-        "kernel/render_score_pallas_interpret",
+        f"kernel/render_score_{mode}",
         t_k * 1e6,
-        f"particle_px_sphere_per_s={work / t_k:.2e};interpret=True",
+        f"particle_px_sphere_per_s={work / t_k:.2e};interpret={interp}",
     ))
 
     # second kernel: fused swarm update
@@ -64,9 +69,9 @@ def bench() -> list:
         x, v, pb, gb, r1, r2, lo, hi,
     )
     rows.append((
-        "kernel/pso_update_pallas_interpret",
+        f"kernel/pso_update_{mode}",
         t_upd * 1e6,
-        f"particle_dims_per_s={np_ * d / t_upd:.2e};interpret=True",
+        f"particle_dims_per_s={np_ * d / t_upd:.2e};interpret={interp}",
     ))
 
     # edge batching: B clients' swarms in ONE fused launch vs B launches
@@ -77,10 +82,10 @@ def bench() -> list:
         tile(x), tile(v), tile(pb), tile(gb), tile(r1), tile(r2), lo, hi,
     )
     rows.append((
-        f"kernel/pso_update_batched_b{b}_pallas_interpret",
+        f"kernel/pso_update_batched_b{b}_{mode}",
         t_fused * 1e6,
         f"particle_dims_per_s={b * np_ * d / t_fused:.2e};"
-        f"per_client_vs_solo={t_fused / (b * t_upd):.2f};interpret=True",
+        f"per_client_vs_solo={t_fused / (b * t_upd):.2f};interpret={interp}",
     ))
 
     # payload codec: delta-encode + quantize-pack one depth plane (the
@@ -100,18 +105,18 @@ def bench() -> list:
     # wire width is priced by the model/ref.encode_frame, not here
     enc_bytes = cref.encoded_nbytes_exact(mask, bits=32, header_nbytes=64)
     rows.append((
-        "kernel/codec_delta_encode_pallas_interpret",
+        f"kernel/codec_delta_encode_{mode}",
         t_delta * 1e6,
         f"bytes_per_s={raw_bytes / t_delta:.2e};"
-        f"wire_ratio={enc_bytes / raw_bytes:.3f};interpret=True",
+        f"wire_ratio={enc_bytes / raw_bytes:.3f};interpret={interp}",
     ))
     t_q = time_fn(
         jax.jit(lambda f: ckern.quantize_pack(f, 0.0, 2.0, bits=8)), frame
     )
     rows.append((
-        "kernel/codec_quantize_pack_pallas_interpret",
+        f"kernel/codec_quantize_pack_{mode}",
         t_q * 1e6,
-        f"bytes_per_s={raw_bytes / t_q:.2e};pack_ratio=0.25;interpret=True",
+        f"bytes_per_s={raw_bytes / t_q:.2e};pack_ratio=0.25;interpret={interp}",
     ))
     return rows
 

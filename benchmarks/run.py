@@ -23,6 +23,7 @@ from benchmarks import (
     topology_bench,
 )
 from benchmarks.common import emit
+from repro.launch import compile_cache
 
 MODULES = [
     ("fig3", fig3_framedrop),
@@ -58,4 +59,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

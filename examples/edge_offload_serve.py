@@ -18,6 +18,7 @@ from repro.core import offload, pso, tracker
 from repro.core.camera import Camera
 from repro.core.offload import Policy
 from repro.data import rgbd
+from repro.launch import compile_cache
 from repro.sim import hardware, runtime
 
 
@@ -71,4 +72,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -48,6 +48,7 @@ from repro.cluster import (
 from repro.cluster.fleet import ServiceDrift
 from repro.codec import CodecConfig, sequence_motion
 from repro.core.offload import Policy
+from repro.launch import compile_cache
 from repro.net import links
 from repro.sim import hardware
 
@@ -258,4 +259,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.configs import registry
 from repro.core.offload import Policy
+from repro.launch import compile_cache
 from repro.models import transformer
 from repro.serving import edge
 from repro.serving.engine import Engine, Request
@@ -74,4 +75,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -16,6 +16,7 @@ import numpy as np
 from repro.core import pso, tracker
 from repro.core.camera import Camera
 from repro.data import rgbd
+from repro.launch import compile_cache
 
 
 def main() -> None:
@@ -56,4 +57,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -5,7 +5,7 @@
 
 import argparse
 
-from repro.launch import train
+from repro.launch import compile_cache, train
 
 
 def main() -> None:
@@ -33,4 +33,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

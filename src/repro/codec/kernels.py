@@ -33,20 +33,19 @@ to tile multiples and slicing back, mirroring ``kernels/ops.py``.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
 from repro.codec.ref import (
     DEFAULT_BLOCK_H,
     DEFAULT_BLOCK_W,
     _check_bits,
     quant_step,
 )
-
-DEFAULT_INTERPRET = True  # CPU container; flip on real TPU.
 
 
 def _pad_plane(x: jnp.ndarray, block_h: int, block_w: int) -> jnp.ndarray:
@@ -95,7 +94,7 @@ def delta_encode(
     threshold: float = 0.0,
     block_h: int = DEFAULT_BLOCK_H,
     block_w: int = DEFAULT_BLOCK_W,
-    interpret: bool = DEFAULT_INTERPRET,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Returns ``(delta_bits (H, W) i32, mask f32)`` — matches
     ``ref.delta_encode`` on tile-aligned shapes.  Unaligned frames are
@@ -119,7 +118,7 @@ def delta_encode(
             jax.ShapeDtypeStruct((hp, wp), jnp.int32),
             jax.ShapeDtypeStruct(grid, jnp.float32),
         ],
-        interpret=interpret,
+        interpret=kernels.resolve_interpret(interpret),
     )(f, r)
     return delta[:h, :w], mask
 
@@ -133,7 +132,7 @@ def delta_decode(
     *,
     block_h: int = DEFAULT_BLOCK_H,
     block_w: int = DEFAULT_BLOCK_W,
-    interpret: bool = DEFAULT_INTERPRET,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Reconstruct the frame: bit-exact on changed tiles, reference
     passthrough (error <= encode threshold) on unchanged ones."""
@@ -148,7 +147,7 @@ def delta_decode(
         in_specs=[tile, tile],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((hp, wp), jnp.float32),
-        interpret=interpret,
+        interpret=kernels.resolve_interpret(interpret),
     )(d, r)
     return out[:h, :w]
 
@@ -164,7 +163,7 @@ def delta_encode_batched(
     threshold: float = 0.0,
     block_h: int = DEFAULT_BLOCK_H,
     block_w: int = DEFAULT_BLOCK_W,
-    interpret: bool = DEFAULT_INTERPRET,
+    interpret: Optional[bool] = None,
     path: str = "grid",
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """B clients' frames delta-encoded in ONE fused launch — the edge
@@ -200,7 +199,7 @@ def delta_encode_batched(
             jax.ShapeDtypeStruct((b, hp, wp), jnp.int32),
             jax.ShapeDtypeStruct(grid, jnp.float32),
         ],
-        interpret=interpret,
+        interpret=kernels.resolve_interpret(interpret),
     )(f, r)
     return delta[:, :h, :w], mask
 
@@ -231,7 +230,7 @@ def significant_bit_widths(
     *,
     block_h: int = DEFAULT_BLOCK_H,
     block_w: int = DEFAULT_BLOCK_W,
-    interpret: bool = DEFAULT_INTERPRET,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Per-tile significant-bit widths of a residual plane:
     ``(ceil(H/bh), ceil(W/bw)) i32`` in [0, 32].  This is the entropy
@@ -250,7 +249,7 @@ def significant_bit_widths(
         in_specs=[tile],
         out_specs=cell,
         out_shape=jax.ShapeDtypeStruct(grid, jnp.int32),
-        interpret=interpret,
+        interpret=kernels.resolve_interpret(interpret),
     )(d)
 
 
@@ -262,7 +261,7 @@ def significant_bit_widths_batched(
     *,
     block_h: int = DEFAULT_BLOCK_H,
     block_w: int = DEFAULT_BLOCK_W,
-    interpret: bool = DEFAULT_INTERPRET,
+    interpret: Optional[bool] = None,
     path: str = "grid",
 ) -> jnp.ndarray:
     """B clients' residual planes width-scanned in one fused launch;
@@ -289,7 +288,7 @@ def significant_bit_widths_batched(
         in_specs=[tile],
         out_specs=cell,
         out_shape=jax.ShapeDtypeStruct(grid, jnp.int32),
-        interpret=interpret,
+        interpret=kernels.resolve_interpret(interpret),
     )(d)
 
 
@@ -336,7 +335,7 @@ def quantize_pack(
     bits: int = 8,
     block_h: int = DEFAULT_BLOCK_H,
     block_w: int = DEFAULT_BLOCK_W,
-    interpret: bool = DEFAULT_INTERPRET,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Quantize depth to ``bits``-wide codes and bit-pack the lane axis
     into int32 words: returns ``(H, W * bits / 32) i32``.  Requires
@@ -358,7 +357,7 @@ def quantize_pack(
         in_specs=[tile],
         out_specs=out_tile,
         out_shape=jax.ShapeDtypeStruct((hp, wp // ratio), jnp.int32),
-        interpret=interpret,
+        interpret=kernels.resolve_interpret(interpret),
     )(x)
     return words[:h, : w // ratio]
 
@@ -375,7 +374,7 @@ def unpack_dequantize(
     bits: int = 8,
     block_h: int = DEFAULT_BLOCK_H,
     block_w: int = DEFAULT_BLOCK_W,
-    interpret: bool = DEFAULT_INTERPRET,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Inverse of :func:`quantize_pack`: ``(H, W) f32`` with per-pixel
     error <= ``ref.quant_step(lo, hi, bits) / 2`` inside [lo, hi]."""
@@ -395,7 +394,7 @@ def unpack_dequantize(
         in_specs=[in_tile],
         out_specs=out_tile,
         out_shape=jax.ShapeDtypeStruct((hp, wpp * ratio), jnp.float32),
-        interpret=interpret,
+        interpret=kernels.resolve_interpret(interpret),
     )(x)
     return out[:h, : wpk * ratio]
 
@@ -414,7 +413,7 @@ def quantize_pack_batched(
     bits: int = 8,
     block_h: int = DEFAULT_BLOCK_H,
     block_w: int = DEFAULT_BLOCK_W,
-    interpret: bool = DEFAULT_INTERPRET,
+    interpret: Optional[bool] = None,
     path: str = "grid",
 ) -> jnp.ndarray:
     """Fused multi-client quantize+pack: ``(B, H, W * bits / 32) i32``;
@@ -449,6 +448,6 @@ def quantize_pack_batched(
         in_specs=[tile],
         out_specs=out_tile,
         out_shape=jax.ShapeDtypeStruct((b, hp, wp // ratio), jnp.int32),
-        interpret=interpret,
+        interpret=kernels.resolve_interpret(interpret),
     )(x)
     return words[:, :h, : w // ratio]

@@ -45,11 +45,17 @@ def sphere_depth(rays: jnp.ndarray, spheres: jnp.ndarray) -> jnp.ndarray:
     their discriminant is  (d.c)^2 - |d|^2 |c|^2 <= 0 (Cauchy-Schwarz),
     with equality only for rays through the center — give them |c|=0 and
     the near root is t=0, rejected by the t>eps test.
+
+    The discriminant cancels: (d.c)^2 and |d|^2 (|c|^2 - r^2) are both
+    ~0.25 m^2 while their difference is ~r^2 ~ 1e-4 m^2. So d.c is a
+    float32 product at HIGHEST precision: a TPU runs a default-precision
+    float32 matmul as one bfloat16 pass, and that flipped most silhouette
+    pixels between hit and miss on a TPU v5e.
     """
     d2 = jnp.sum(rays * rays, axis=-1)  # (P,)
     c = spheres[:, :3]  # (S, 3)
     r = spheres[:, 3]  # (S,)
-    dc = rays @ c.T  # (P, S)
+    dc = jnp.matmul(rays, c.T, precision=jax.lax.Precision.HIGHEST)  # (P, S)
     c2r2 = jnp.sum(c * c, axis=-1) - r * r  # (S,)
     disc = dc * dc - d2[:, None] * c2r2[None, :]  # (P, S)
     safe_disc = jnp.maximum(disc, 0.0)

@@ -183,19 +183,12 @@ def sharded_eval(
     device evaluates N/devices particles; scores are all-gathered (tiny:
     N floats), so the only collective in the PSO loop is O(N) bytes.
     """
-    import functools
-
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    @functools.partial(
-        shard_map,
+    return jax.shard_map(
+        eval_fn,
         mesh=mesh,
         in_specs=(P(axis, None),),
         out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )
-    def _eval(chunk):
-        return eval_fn(chunk)
-
-    return _eval

@@ -13,6 +13,24 @@ fleet simulator (``repro.cluster``) prices with its
 ``BatchServiceModel``.  B=1 reproduces the unbatched kernels
 bit-for-bit (tests/test_batching.py).
 
-All validate under interpret=True on this CPU container and target TPU
-VMEM tiling via explicit BlockSpecs.
+Every kernel entry point takes ``interpret=None`` by default, which
+``resolve_interpret`` turns into the platform's mode: Mosaic-compiled on
+a TPU, the Pallas interpreter on the CPU backend.
 """
+
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+
+
+def default_interpret() -> bool:
+    """True only where the default backend is the CPU: there Pallas can
+    only interpret. A TPU always runs the compiled kernel."""
+    return jax.default_backend() == "cpu"
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """An explicit ``interpret`` wins; ``None`` follows the platform."""
+    return default_interpret() if interpret is None else bool(interpret)

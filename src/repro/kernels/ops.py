@@ -1,7 +1,8 @@
 """Jit'd public wrapper around the render_score Pallas kernel.
 
 Handles shape padding (particles to block_n, pixels to block_p), mask
-normalization, and the interpret-mode switch. This is the drop-in
+normalization, and passes ``interpret`` through (``None`` follows the
+platform, see ``repro.kernels.resolve_interpret``). This is the drop-in
 replacement for ``objective.batched_objective``'s vmapped evaluation —
 the tracker selects it with ``TrackerConfig(use_kernel=True)``.
 """
@@ -9,14 +10,13 @@ the tracker selects it with ``TrackerConfig(use_kernel=True)``.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.objective import CLAMP_T
 from repro.kernels import render_score as _kernel
-
-DEFAULT_INTERPRET = True  # CPU container; flip on real TPU.
 
 
 def _pad_to(x: jnp.ndarray, size: int, axis: int, value=0.0) -> jnp.ndarray:
@@ -65,25 +65,16 @@ def render_score(
     block_n: int = _kernel.DEFAULT_BLOCK_N,
     block_p: int = _kernel.DEFAULT_BLOCK_P,
     clamp_t: float = CLAMP_T,
-    interpret: bool = DEFAULT_INTERPRET,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    """Normalized E_D per particle, shape (N,). Matches ref.render_score."""
-    n = spheres.shape[0]
-    spheres_p, rays_p, depth_p, mask_p = _pad_render_inputs(
-        spheres, rays, depth_obs, mask, block_n, block_p
-    )
-    sums = _kernel.render_score_sums(
-        spheres_p,
-        rays_p,
-        depth_p,
-        mask_p,
-        block_n=block_n,
-        block_p=block_p,
-        clamp_t=clamp_t,
+    """Normalized E_D per particle, shape (N,). Matches ref.render_score.
+
+    The single-client case of ``render_score_batched`` (B = 1)."""
+    return render_score_batched(
+        spheres[None], rays[None], depth_obs[None], mask[None],
+        block_n=block_n, block_p=block_p, clamp_t=clamp_t,
         interpret=interpret,
-    )[:n]
-    denom = jnp.maximum(jnp.sum(mask.astype(jnp.float32)), 1.0)
-    return sums / denom
+    )[0]
 
 
 @functools.partial(
@@ -99,7 +90,7 @@ def render_score_batched(
     block_n: int = _kernel.DEFAULT_BLOCK_N,
     block_p: int = _kernel.DEFAULT_BLOCK_P,
     clamp_t: float = CLAMP_T,
-    interpret: bool = DEFAULT_INTERPRET,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Normalized E_D per (client, particle), shape (B, N) — B clients'
     populations scored in ONE fused kernel launch (edge batching).

@@ -30,10 +30,13 @@ fallback vmaps the unbatched call for comparison/debugging.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro import kernels
 
 DEFAULT_BLOCK_N = 8
 
@@ -84,7 +87,7 @@ def pso_update(
     social: float,
     velocity_clip: float,
     block_n: int = DEFAULT_BLOCK_N,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Returns (new_positions, new_velocities), both (N, D) f32."""
     n, d = x.shape
@@ -109,7 +112,7 @@ def pso_update(
             jax.ShapeDtypeStruct((n, d), jnp.float32),
             jax.ShapeDtypeStruct((n, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=kernels.resolve_interpret(interpret),
     )(
         x.astype(jnp.float32), v.astype(jnp.float32),
         pbest.astype(jnp.float32), r1.astype(jnp.float32),
@@ -132,7 +135,7 @@ def pso_update_batched(
     social: float,
     velocity_clip: float,
     block_n: int = DEFAULT_BLOCK_N,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     path: str = "grid",
 ):
     """Fused multi-swarm update: (new_positions, new_velocities), (B, N, D).
@@ -183,7 +186,7 @@ def pso_update_batched(
             jax.ShapeDtypeStruct((b, n, d), jnp.float32),
             jax.ShapeDtypeStruct((b, n, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=kernels.resolve_interpret(interpret),
     )(
         x.astype(jnp.float32), v.astype(jnp.float32),
         pbest.astype(jnp.float32), r1.astype(jnp.float32),

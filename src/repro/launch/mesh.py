@@ -18,7 +18,16 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...], devices) -> Mesh:
+    """``jax.make_mesh`` with Auto axis types: the compiler propagates
+    shardings, so a gather from a sharded array (the swarm's argmin
+    pick) lowers with a collective instead of a sharding-type error."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes), devices=devices
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -33,7 +42,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
             f"mesh needs {n} devices but only {len(devices)} exist — run "
             "under dryrun.py (it forces 512 host platform devices)"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _auto_mesh(shape, axes, devices[:n])
 
 
 def make_host_mesh(
@@ -45,7 +54,7 @@ def make_host_mesh(
         model = 1
         data = n
     assert data * model == n, (data, model, n)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"), jax.devices())
 
 
 def mesh_device_count(multi_pod: bool) -> int:

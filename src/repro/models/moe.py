@@ -181,7 +181,6 @@ def _expert_combine_shardmap(params, cfg, mesh, buf, meta, s, d, capacity):
     meta — per-row dispatch indices (replicated over model).
     Returns y (B, S, d) batch-sharded, replicated over model.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     baxes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -216,7 +215,7 @@ def _expert_combine_shardmap(params, cfg, mesh, buf, meta, s, d, capacity):
         return jax.lax.psum(y_part, "model")
 
     e_sorted, safe_pos, keep, tok_sorted, gate_sorted = meta
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -231,7 +230,7 @@ def _expert_combine_shardmap(params, cfg, mesh, buf, meta, s, d, capacity):
             P(baxes, None),  # gate_sorted
         ),
         out_specs=P(baxes, None, None),
-        check_rep=False,
+        check_vma=False,
     )(
         params["w_gate"], params["w_up"], params["w_down"], buf,
         e_sorted, safe_pos, keep, tok_sorted, gate_sorted,

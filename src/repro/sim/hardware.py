@@ -1,7 +1,9 @@
 """Device-tier models calibrated against the paper's measurements.
 
-This container has no GTX 1080M, no GeForce 670M and no TPU, so absolute
-tier throughputs are *calibrated anchors*, not measurements: we fix each
+The paper's GTX 1080M and GeForce 670M tiers are not hardware this
+repository runs on, and no tier constant here is fitted to a TPU
+measurement yet, so absolute tier throughputs are *calibrated anchors*,
+not measurements: we fix each
 tier's effective FLOP/s so that the NATIVE (unwrapped, local) tracker hits
 the paper's reported baseline framerates — server > 40 fps, laptop
 ~13 fps (Fig. 4) — for the paper-scale workload. Everything downstream

@@ -8,19 +8,14 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import handmodel as hm
 from repro.core.camera import Camera
-from repro.core.objective import CLAMP_T
 from repro.kernels import ops, ref
 
 
 def _assert_scores_close(a, b, mask):
-    """Kernel vs oracle comparison that tolerates ONE silhouette-pixel
-    hit flip per particle: at grazing rays the sphere discriminant is
-    ~0 and f32 accumulation order (dot_general in the kernel vs matmul
-    in the oracle) can legitimately flip hit/no-hit, shifting the
-    normalized score by at most CLAMP_T / |B|."""
-    denom = max(float(np.asarray(mask, dtype=np.float32).sum()), 1.0)
-    atol = CLAMP_T / denom + 1e-6
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5, atol=atol)
+    """Kernel vs oracle comparison within ``ref.score_atol``: one
+    silhouette-pixel hit flip per particle."""
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
+                               atol=ref.score_atol(mask))
 
 
 def _inputs(n_particles, w, h, seed=0, dtype=jnp.float32):

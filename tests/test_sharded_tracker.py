@@ -24,8 +24,9 @@ import jax, jax.numpy as jnp
 import numpy as np
 from repro.core import handmodel, objective, pso, tracker
 from repro.core.camera import Camera
+from repro.launch.mesh import make_host_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"), devices=jax.devices())
+mesh = make_host_mesh(data=2, model=4)
 cam = Camera(width=24, height=24, fx=22.0, fy=22.0, cx=11.5, cy=11.5)
 h0 = handmodel.default_pose(0.45)
 depth = objective.render_depth(h0, cam)
@@ -75,7 +76,9 @@ def test_sharded_tracker_on_8_fake_devices():
         proc = subprocess.run(
             [sys.executable, "-c", SCRIPT],
             capture_output=True, text=True, timeout=SUBPROC_TIMEOUT,
-            env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin"},
+            # the child inherits the caller's environment, pinned to the
+            # CPU backend: its 8 devices are virtual host devices
+            env={**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"},
             cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         )
     except subprocess.TimeoutExpired:
