@@ -20,8 +20,10 @@ result before submitting the next.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
+import time
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -52,15 +54,17 @@ def _make_eval_fn(
         rays = cfg.camera.rays_flat()
 
         def eval_fn(hs: jnp.ndarray) -> jnp.ndarray:
-            spheres = jax.vmap(handmodel.pack_spheres)(hs)
-            return kernel_ops.render_score(
-                spheres, rays, d_o.reshape(-1), mask.reshape(-1)
-            )
+            with jax.named_scope("tracker.evaluate"):
+                spheres = jax.vmap(handmodel.pack_spheres)(hs)
+                return kernel_ops.render_score(
+                    spheres, rays, d_o.reshape(-1), mask.reshape(-1)
+                )
 
         return eval_fn
 
     def eval_fn(hs: jnp.ndarray) -> jnp.ndarray:
-        return objective.batched_objective(hs, d_o, cfg.camera, mask)
+        with jax.named_scope("tracker.evaluate"):
+            return objective.batched_objective(hs, d_o, cfg.camera, mask)
 
     return eval_fn
 
@@ -122,16 +126,28 @@ def stage_refine(
 # ---------------------------------------------------------------------------
 
 
+def _track(cfg: TrackerConfig, key: jax.Array, h_prev: jnp.ndarray,
+           depth: jnp.ndarray, wrap_eval: Callable[[pso.EvalFn], pso.EvalFn]):
+    """One frame's four stages, each under its own ``jax.named_scope``
+    (``tracker.preprocess`` ... ``tracker.refine``), so a device trace
+    reads by stage."""
+    with jax.named_scope("tracker.preprocess"):
+        d_o, mask = stage_preprocess(cfg, h_prev, depth)
+    eval_fn = wrap_eval(_make_eval_fn(cfg, d_o, mask))
+    with jax.named_scope("tracker.spawn"):
+        state, lo, hi = stage_spawn(cfg, key, h_prev, eval_fn)
+    with jax.named_scope("tracker.optimize"):
+        state = stage_optimize(cfg, state, lo, hi, eval_fn)
+    with jax.named_scope("tracker.refine"):
+        return stage_refine(cfg, state, h_prev)
+
+
 def make_track_frame(cfg: TrackerConfig) -> Callable:
     """Build the jitted (key, h_prev, depth) -> (h_next, score) step."""
 
     @jax.jit
     def track_frame(key: jax.Array, h_prev: jnp.ndarray, depth: jnp.ndarray):
-        d_o, mask = stage_preprocess(cfg, h_prev, depth)
-        eval_fn = _make_eval_fn(cfg, d_o, mask)
-        state, lo, hi = stage_spawn(cfg, key, h_prev, eval_fn)
-        state = stage_optimize(cfg, state, lo, hi, eval_fn)
-        return stage_refine(cfg, state, h_prev)
+        return _track(cfg, key, h_prev, depth, lambda eval_fn: eval_fn)
 
     return track_frame
 
@@ -142,14 +158,53 @@ def make_track_frame_sharded(cfg: TrackerConfig, mesh, axis: str = "model"):
 
     @jax.jit
     def track_frame(key: jax.Array, h_prev: jnp.ndarray, depth: jnp.ndarray):
-        d_o, mask = stage_preprocess(cfg, h_prev, depth)
-        base_eval = _make_eval_fn(cfg, d_o, mask)
-        eval_fn = pso.sharded_eval(base_eval, mesh, axis)
-        state, lo, hi = stage_spawn(cfg, key, h_prev, eval_fn)
-        state = stage_optimize(cfg, state, lo, hi, eval_fn)
-        return stage_refine(cfg, state, h_prev)
+        return _track(cfg, key, h_prev, depth,
+                      lambda eval_fn: pso.sharded_eval(eval_fn, mesh, axis))
 
     return track_frame
+
+
+class StepPhases:
+    """Where ``Tracker.step`` spends host time, always counted.
+
+    Each step is a ``tracker.step`` profiler span that carries the frame
+    number, with one child span per phase (``tracker.key_split``,
+    ``tracker.dispatch``, ``tracker.wait``). The spans are written only
+    while ``jax.profiler`` traces, into the trace that holds the device's
+    operations. The counters cost one ``perf_counter`` read per phase:
+    frames stepped, the host seconds of each phase summed over them, and
+    the phase split of the slowest step, the first (compiling) step
+    included; a caller that wants them from a later frame on replaces
+    ``Tracker.phases`` with a new ``StepPhases``."""
+
+    PHASES = ("key_split", "dispatch", "wait")
+
+    def __init__(self):
+        self.frames = 0
+        self.seconds = dict.fromkeys(self.PHASES, 0.0)
+        self.slowest = dict.fromkeys(self.PHASES, 0.0)
+        self._split: dict = {}
+        self._mark = 0.0
+
+    @contextlib.contextmanager
+    def step(self):
+        self._split = {}
+        self._mark = time.perf_counter()
+        with jax.profiler.TraceAnnotation("tracker.step", frame=self.frames):
+            yield
+        for name, s in self._split.items():
+            self.seconds[name] += s
+        if sum(self._split.values()) > sum(self.slowest.values()):
+            self.slowest = self._split
+        self.frames += 1
+
+    @contextlib.contextmanager
+    def phase(self, name: str):
+        with jax.profiler.TraceAnnotation(f"tracker.{name}"):
+            yield
+        now = time.perf_counter()
+        self._split[name] = now - self._mark
+        self._mark = now
 
 
 class Tracker:
@@ -161,11 +216,17 @@ class Tracker:
         self.h = h0 if h0 is not None else handmodel.default_pose()
         self.key = jax.random.PRNGKey(seed)
         self._step = make_track_frame(cfg)
+        self.phases = StepPhases()
 
     def step(self, depth: jnp.ndarray) -> Tuple[jnp.ndarray, float]:
-        self.key, sub = jax.random.split(self.key)
-        self.h, score = self._step(sub, self.h, depth)
-        return self.h, float(score)
+        with self.phases.step():
+            with self.phases.phase("key_split"):
+                self.key, sub = jax.random.split(self.key)
+            with self.phases.phase("dispatch"):
+                self.h, score = self._step(sub, self.h, depth)
+            with self.phases.phase("wait"):
+                score = float(score)
+        return self.h, score
 
 
 # ---------------------------------------------------------------------------

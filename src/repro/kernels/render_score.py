@@ -182,5 +182,6 @@ def render_score_sums_batched(
         out_specs=pl.BlockSpec((1, block_n, 1, 1), lambda b, i, j: (b, i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, n, 1, 1), jnp.float32),
         interpret=kernels.resolve_interpret(interpret),
+        name="render_score",  # the kernel's name in a profile, whatever calls it
     )(spheres.astype(jnp.float32), rays_t, depth, mask)
     return sums.reshape(bsz, n)

@@ -1,5 +1,8 @@
 """End-to-end tracker behaviour on synthetic sequences."""
 
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -96,3 +99,72 @@ def test_executed_simulation_couples_drops_to_quality():
     r_slow = runtime.executed_run(tcfg, env_slow, Policy.LOCAL, frames, truth)
     assert r_slow.sim.stats.dropped > r_fast.sim.stats.dropped
     assert len(r_fast.sim.stats.processed) > len(r_slow.sim.stats.processed)
+
+
+TINY = Camera(width=16, height=16, fx=14.0, fy=14.0, cx=7.5, cy=7.5)
+TINY_CFG = tracker.TrackerConfig(
+    camera=TINY, pso=pso.PSOConfig(num_particles=8, num_generations=2)
+)
+SCOPES = ("tracker.preprocess", "tracker.spawn", "tracker.optimize",
+          "tracker.refine", "tracker.evaluate")
+
+
+@pytest.mark.parametrize("use_kernel", [False, True], ids=["jnp", "kernel"])
+def test_frame_step_carries_the_tracker_scopes(use_kernel):
+    """Every stage and the population evaluation run under their own
+    ``jax.named_scope``, which the compiled step keeps in each op's
+    metadata, so a device trace can be read by stage; spawn's evaluation
+    and optimize's share one scope."""
+    cfg = dataclasses.replace(TINY_CFG, use_kernel=use_kernel)
+    compiled = tracker.make_track_frame(cfg).lower(
+        jax.random.PRNGKey(0), hm.default_pose(), jnp.zeros((16, 16))
+    ).compile()
+    op_names = set(re.findall(r'op_name="([^"]*)"', compiled.as_text()))
+    innermost = {re.findall(r"tracker\.\w+", n)[-1] for n in op_names if "tracker." in n}
+    assert innermost == set(SCOPES)
+    evaluated_in = {n.split("/tracker.evaluate/")[0] for n in op_names if "/tracker.evaluate/" in n}
+    assert any("tracker.spawn" in n for n in evaluated_in)
+    assert any("tracker.optimize" in n for n in evaluated_in)
+
+
+def test_step_counts_its_phases():
+    t = tracker.Tracker(TINY_CFG, h0=hm.default_pose())
+    phases = tracker.StepPhases.PHASES
+    assert t.phases.frames == 0
+    t.step(jnp.zeros((16, 16)))
+    assert t.phases.frames == 1
+    assert set(t.phases.seconds) == set(phases)
+    assert all(s >= 0 for s in t.phases.seconds.values())
+    assert t.phases.slowest == t.phases.seconds  # one step: it is the slowest
+    first = dict(t.phases.seconds)
+    t.step(jnp.zeros((16, 16)))
+    second = {p: t.phases.seconds[p] - first[p] for p in phases}
+    assert t.phases.frames == 2
+    slowest = max(sum(first.values()), sum(second.values()))
+    assert sum(t.phases.slowest.values()) == pytest.approx(slowest)
+
+
+def test_step_writes_its_spans_when_the_profiler_traces(tmp_path):
+    """The spans of ``Tracker.step`` land in the profiler's trace, nested
+    in ``tracker.step``, which carries the frame number."""
+    from jax.profiler import ProfileData
+
+    t = tracker.Tracker(TINY_CFG, h0=hm.default_pose())
+    t.step(jnp.zeros((16, 16)))  # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        t.step(jnp.zeros((16, 16)))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    spans = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats))
+             for plane in ProfileData.from_file(str(path)).planes
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("tracker.")]
+    names = [n for n, *_ in spans]
+    assert sorted(names) == sorted(
+        ["tracker.step"] + [f"tracker.{p}" for p in tracker.StepPhases.PHASES])
+    (outer,) = [s for s in spans if s[0] == "tracker.step"]
+    assert outer[3]["frame"] == 1
+    for name, start, end, _ in spans:
+        assert outer[1] <= start <= end <= outer[2], name
