@@ -7,6 +7,13 @@ is a bounding box containing the hand. The render is analytic sphere
 ray-casting (DESIGN.md §2 explains why this replaces the paper's CUDA
 rasterizer on TPU).
 
+Like the paper's tracker, the population evaluation renders only inside
+B: ``batched_objective`` moves B's pixels to the front of the frame and
+renders and scores the first K of them, K the smallest rung of a ladder
+fixed by the frame's pixel count (``box_ladder``, ``box_rung``) with
+K >= |B|. Every pixel outside B adds an exact zero to the sum, so this
+changes only the order in which the nonzero terms are added.
+
 This module is the *reference* (pure jnp) implementation; the Pallas
 kernel in ``repro.kernels.render_score`` computes the same quantity with
 explicit VMEM tiling, and ``repro.kernels.ref`` re-exports these functions
@@ -15,7 +22,7 @@ as the kernel oracle.
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -111,6 +118,29 @@ def objective(
     return discrepancy(d_h, d_o, mask)
 
 
+LANE = 128  # pixels in a lane row: every rung but a small frame's top is a multiple
+
+
+def box_ladder(num_pixels: int) -> Tuple[int, ...]:
+    """The rungs K of the pixel ladder of a frame of ``num_pixels`` pixels.
+
+    P/16, P/8, P/4, P/2 and P, each rounded up to a whole lane row and
+    capped at P, equal rungs merged: (1024, 2048, 4096, 8192, 16384) at
+    128x128, (128, 256, 512, 1024) at 32x32. The spacing is 2x, so a
+    rung holds at most twice the pixels of B but for the lowest."""
+    rungs = (min(num_pixels, -(-num_pixels // (LANE * d)) * LANE)
+             for d in (16, 8, 4, 2, 1))
+    return tuple(sorted(set(rungs)))
+
+
+def box_rung(n, num_pixels: int):
+    """The smallest rung K >= n of ``box_ladder(num_pixels)``: the pixels
+    rendered for a bounding box of n pixels (n <= num_pixels). Takes a
+    Python or a traced integer; returns a scalar array."""
+    ladder = jnp.asarray(box_ladder(num_pixels))
+    return ladder[jnp.sum(ladder < n)]
+
+
 def batched_objective(
     hs: jnp.ndarray,
     d_o: jnp.ndarray,
@@ -122,8 +152,54 @@ def batched_objective(
     This is the GPGPU-parallel evaluation the paper offloads; the Pallas
     kernel path (repro.kernels.ops.render_score) computes the same thing
     with explicit tiling and is swapped in by the tracker when enabled.
+
+    With a mask, only B's pixels are rendered. A stable partition puts
+    B's n pixels first; the rays and d_o are gathered into that order, and
+    the population is rendered and scored over the first K =
+    ``box_rung(n, P)`` of them, slots past n masked out, the sum divided
+    by max(n, 1) as ``discrepancy`` does. The terms left out are those a
+    full-frame render multiplies by 0, so the score is E_D over B exactly,
+    summed in another order. Under a trace, one ``lax.switch`` branch per
+    rung, each under ``jax.named_scope(f"pixels_{K}")``, picks K outside
+    the vmap over particles. The partition and the gather depend only on
+    the frame, so XLA runs them once a frame, outside the PSO loop.
     """
-    return jax.vmap(lambda h: objective(h, d_o, camera, mask))(hs)
+    if mask is None:
+        return jax.vmap(lambda h: objective(h, d_o, camera))(hs)
+    inside = mask.reshape(-1).astype(bool)
+    num_pixels = inside.shape[0]
+    n = jnp.sum(inside, dtype=jnp.int32)
+    # B's pixels first, in frame order: a sort of one integer key per pixel,
+    # its index, plus P outside B. At 320x240 on a TPU v5e the sort took
+    # 0.10 ms against 0.68 for `jnp.nonzero`'s scatter-add, and one gather
+    # of (ray, depth) rows 0.18 ms against 0.82 for a gather of each. XLA
+    # runs both once a frame, outside the PSO loop; a sort that carries
+    # the indices as a second operand stays inside it.
+    pixel = jnp.arange(num_pixels, dtype=jnp.int32)
+    order = jnp.sort(jnp.where(inside, pixel, pixel + num_pixels)) % num_pixels
+    pixels = jnp.concatenate([camera.rays_flat(), d_o.reshape(-1, 1)], axis=1)[order]
+    rays, observed = pixels[:, :3], pixels[:, 3]
+    ladder = box_ladder(num_pixels)
+
+    def rung(k: int):
+        def evaluate(spheres):
+            with jax.named_scope(f"pixels_{k}"):
+                in_box = jnp.arange(k) < n
+
+                def score(s):
+                    return discrepancy(sphere_depth(rays[:k], s), observed[:k], in_box)
+                return jax.vmap(score)(spheres)
+        return evaluate
+
+    rendered = box_rung(n, num_pixels)
+    spheres = jax.vmap(handmodel.pack_spheres)(hs)
+    if not isinstance(rendered, jax.core.Tracer):
+        # Called outside a trace, the count is on the host: run that rung
+        # alone and op by op, as the rest of the call runs, rather than
+        # compiling every rung for one call.
+        return rung(int(rendered))(spheres)
+    index = jnp.sum(jnp.asarray(ladder) < rendered)
+    return jax.lax.switch(index, [rung(k) for k in ladder], spheres)
 
 
 def bounding_box_mask(

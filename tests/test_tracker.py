@@ -127,6 +127,30 @@ def test_frame_step_carries_the_tracker_scopes(use_kernel):
     assert any("tracker.optimize" in n for n in evaluated_in)
 
 
+def test_each_pixel_rung_runs_under_its_scope_inside_evaluate():
+    """The population evaluation has one branch per rung of the pixel
+    ladder, each under ``pixels_<K>`` in the compiled step's metadata,
+    and the benchmark's scope reduction still charges every op of them to
+    ``tracker.evaluate``."""
+    from bench import scopes
+
+    cam = Camera(width=32, height=32, fx=28.0, fy=28.0, cx=15.5, cy=15.5)
+    cfg = dataclasses.replace(TINY_CFG, camera=cam)
+    text = tracker.make_track_frame(cfg).lower(
+        jax.random.PRNGKey(0), hm.default_pose(), jnp.zeros((32, 32))
+    ).compile().as_text()
+    by_op = scopes.op_scopes(text)
+    rungs = {}
+    for op, op_name in re.findall(
+            r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?metadata=\{[^}]*?op_name="([^"]*)"',
+            text, re.M):
+        rung = re.search(r"/pixels_(\d+)/", op_name)
+        if rung:
+            rungs.setdefault(int(rung.group(1)), set()).add(by_op.get(op))
+    assert sorted(rungs) == list(objective.box_ladder(cam.num_pixels)) == [128, 256, 512, 1024]
+    assert all(charged == {"tracker.evaluate"} for charged in rungs.values())
+
+
 def test_step_counts_its_phases():
     t = tracker.Tracker(TINY_CFG, h0=hm.default_pose())
     phases = tracker.StepPhases.PHASES
