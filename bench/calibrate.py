@@ -44,7 +44,7 @@ def main(argv=None) -> int:
         if fault != planted:
             if undo:
                 undo()
-            undo = faults.plant(fault) if fault else None
+            undo = faults.plant(fault, cell.reference) if fault else None
             planted = fault
         r = harness.run_cell(cell, seed, args.seconds, False, PROCESS_START)
         print(json.dumps({

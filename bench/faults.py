@@ -1,14 +1,15 @@
 """The control and the faults that ``correct`` has to refuse.
 
 Each is planted in the program under the timed path, in this process,
-by ``plant(name)``, which returns a function that takes it out again.
+by ``plant(name, ref)``, which returns a function that takes it out
+again; ``ref`` is the cell's reference module (``Cell.reference``).
 ``bench/calibrate.py`` reads them on the chip at a cell's own sizes; the
 tests in ``bench/tests/test_correct.py`` drive whole runs with each.
 
 * ``control``: the reference put in the program's place, the population
-  evaluation computed by ``bench.reference.score`` with the
-  ``<ray, centre>`` product at ``high`` precision, one step below the
-  ``highest`` that the configurations state.
+  evaluation computed by the reference's ``score`` with the
+  ``<ray, centre>`` product at ``high`` precision (``dot_high``), one step
+  below the ``highest`` that the configurations state.
 * ``state_unchanged``: a step that returns its state unchanged.
 * ``answer_altered``: the pose altered by 5 mm where it is produced.
 * ``half_the_pixels``: half of the pixels left out of the score, the mean
@@ -23,20 +24,20 @@ tests in ``bench/tests/test_correct.py`` drive whole runs with each.
 
 from __future__ import annotations
 
+import functools
 import threading
 
 import jax
 import jax.numpy as jnp
 
-from bench import reference
 
-
-def control_objective(hs, d_o, camera, mask):
-    """E_D of each hypothesis by the reference at ``high`` precision, with
-    the signature of ``repro.core.objective.batched_objective``."""
-    rays = reference.camera_rays(vars(camera))
+def control_objective(ref, hs, d_o, camera, mask):
+    """E_D of each hypothesis by the reference ``ref`` at ``high``
+    precision; past ``ref``, the signature of
+    ``repro.core.objective.batched_objective``."""
+    rays = ref.camera_rays(vars(camera))
     d_o, mask = d_o.reshape(-1), mask.reshape(-1)
-    return jax.vmap(lambda h: reference.score(h, d_o, mask, rays, reference.dot_high))(hs)
+    return jax.vmap(lambda h: ref.score(h, d_o, mask, rays, ref.dot_high))(hs)
 
 
 def _patch(target, attr, value):
@@ -63,8 +64,8 @@ def _step_fault(wrap):
     return _patch(tracker.Tracker, "step", wrap(tracker.Tracker.step))
 
 
-def _control():
-    return _objective_fault(lambda _: control_objective)
+def _control(ref):
+    return _objective_fault(lambda _: functools.partial(control_objective, ref))
 
 
 def _half_the_pixels():
@@ -150,6 +151,7 @@ FAULTS = {
 }
 
 
-def plant(name: str):
-    """Plant the fault of that name; returns the function that undoes it."""
-    return FAULTS[name]()
+def plant(name: str, ref):
+    """Plant the fault of that name in a cell whose reference module is
+    ``ref``; returns the function that undoes it."""
+    return _control(ref) if name == "control" else FAULTS[name]()

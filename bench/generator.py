@@ -7,12 +7,11 @@ from ``seed + client``; every seed gives the same number of frames at
 the same sizes, so a seed changes what the hand does, not how much work
 the tracker does.
 
-The trajectory is the repository's default motion (hand at 0.5 m,
-position sweeps, wrist rotation about a wobbling axis, staggered finger
-curls) with a burst of faster motion for ``burst_length`` of every
-``burst_every`` frames. The observations are rendered by
-``bench.reference`` on the device, a fixed number of frames to a call of
-one compiled program, with Gaussian depth noise.
+The hand is the configuration's reference module (``ref``, the
+contract in ``bench/reference.py``): its ``trajectory`` turns the traffic
+file's ``motion`` into true poses, and its ``render`` makes the
+observations on the device, a fixed number of frames to a call of one
+compiled program, with Gaussian depth noise.
 """
 
 from __future__ import annotations
@@ -23,14 +22,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import reference
-
 RENDER_CHUNK = 32  # frames per call of the renderer
 
 
 @dataclasses.dataclass(frozen=True)
 class ClientSequence:
-    """One client's input: frames (T, H, W) on the host and the truth (T, 27)."""
+    """One client's input: frames (T, H, W) on the host and the truth
+    (T, NUM_PARAMS)."""
 
     seed: int
     truth: np.ndarray
@@ -44,56 +42,25 @@ class ClientSequence:
         return j if j < n else 2 * n - 2 - j
 
 
-def trajectory(motion: dict, num_frames: int, offset: int) -> np.ndarray:
-    """(num_frames, 27) poses: frames offset .. offset + num_frames - 1."""
-    total = offset + num_frames
-    t = np.arange(total)
-    in_burst = (t % motion["burst_every"]) >= motion["burst_every"] - motion["burst_length"]
-    speed = np.where(in_burst, motion["burst_speed"], 1.0)
-    phase = (np.cumsum(speed) / 30.0)[offset:]  # seconds at 30 frames/s
-
-    amp = motion["position_amplitude"]
-    hs = np.zeros((num_frames, reference.NUM_PARAMS), np.float64)
-    hs[:, 0] = amp * np.sin(2 * np.pi * 0.35 * phase)
-    hs[:, 1] = amp * 0.6 * np.sin(2 * np.pi * 0.23 * phase + 1.0)
-    hs[:, 2] = motion["base_distance"] + 0.04 * np.sin(2 * np.pi * 0.17 * phase)
-    ang = motion["rotation_amplitude"] * np.sin(2 * np.pi * 0.3 * phase)
-    axis = np.stack(
-        [np.sin(0.7 * phase), np.cos(0.9 * phase), 0.4 * np.ones_like(phase)], axis=-1
-    )
-    axis /= np.linalg.norm(axis, axis=-1, keepdims=True)
-    hs[:, 3] = np.cos(ang / 2)
-    hs[:, 4:7] = axis * np.sin(ang / 2)[:, None]
-    for f in range(5):
-        curl = 0.5 * motion["curl_amplitude"] * (
-            1 - np.cos(2 * np.pi * (0.4 + 0.05 * f) * phase + f)
-        )
-        base = 7 + 4 * f
-        hs[:, base + 1] = curl * 0.9
-        hs[:, base + 2] = curl
-        hs[:, base + 3] = curl * 0.7
-    return hs.astype(np.float32)
-
-
-def _render_fn(camera: dict, noise_std: float):
-    rays = reference.camera_rays(camera)
+def _render_fn(ref, camera: dict, noise_std: float):
+    rays = ref.camera_rays(camera)
     shape = (camera["height"], camera["width"])
 
     @jax.jit
     def render_chunk(truth, key):
-        depth = jax.lax.map(lambda h: reference.render(h, rays), truth, batch_size=8)
+        depth = jax.lax.map(lambda h: ref.render(h, rays), truth, batch_size=8)
         noise = noise_std * jax.random.normal(key, depth.shape, jnp.float32)
         return (depth + noise).reshape((truth.shape[0],) + shape)
 
     return render_chunk
 
 
-def render(camera: dict, noise_std: float, truth: np.ndarray, seed: int) -> np.ndarray:
-    """Noisy depth frames (T, H, W) of the poses ``truth`` (T, 27), on the
-    host. They are rendered on the device RENDER_CHUNK frames to a call, by
-    one compiled program, so that what the inputs hold on the device stays
-    small beside what the tracker holds."""
-    render_chunk = _render_fn(camera, noise_std)
+def render(ref, camera: dict, noise_std: float, truth: np.ndarray, seed: int) -> np.ndarray:
+    """Noisy depth frames (T, H, W) of the poses ``truth`` (T, NUM_PARAMS),
+    on the host. They are rendered on the device RENDER_CHUNK frames to a
+    call, by one compiled program, so that what the inputs hold on the
+    device stays small beside what the tracker holds."""
+    render_chunk = _render_fn(ref, camera, noise_std)
     n = truth.shape[0]
     padded = np.concatenate([truth, np.repeat(truth[-1:], (-n) % RENDER_CHUNK, 0)])
     key = jax.random.PRNGKey(seed)
@@ -103,7 +70,7 @@ def render(camera: dict, noise_std: float, truth: np.ndarray, seed: int) -> np.n
     return np.concatenate(chunks)[:n]
 
 
-def build(camera: dict, traffic: dict, seed: int) -> list[ClientSequence]:
+def build(ref, camera: dict, traffic: dict, seed: int) -> list[ClientSequence]:
     """Every client's sequence for this seed: ``sequence_frames`` frames each."""
     motion = traffic["motion"]
     n = traffic["sequence_frames"]
@@ -112,7 +79,7 @@ def build(camera: dict, traffic: dict, seed: int) -> list[ClientSequence]:
         client_seed = seed + c
         rng = np.random.default_rng(client_seed)
         offset = int(rng.integers(motion["burst_every"])) + c * traffic["client_phase_shift"]
-        truth = trajectory(motion, n, offset)
-        frames = render(camera, motion["noise_std"], truth, client_seed)
+        truth = ref.trajectory(motion, n, offset)
+        frames = render(ref, camera, motion["noise_std"], truth, client_seed)
         clients.append(ClientSequence(client_seed, truth, frames))
     return clients

@@ -2,9 +2,11 @@
 
 Everything a cell is made of is found by name. ``BENCHMARK.json`` names
 the cell's configuration (its ``file``) and traffic mix
-(``bench/traffic/<traffic>.json``); each metric is read by
-``bench/metrics/<metric>.py``, whose ``read(run)`` returns the number or
-None where the run holds nothing for it to read.
+(``bench/traffic/<traffic>.json``); the configuration names its hand
+model's reference module (its ``"reference"``, a module of the ``bench``
+package with the contract of ``bench/reference.py``); each metric is read
+by ``bench/metrics/<metric>.py``, whose ``read(run)`` returns the number
+or None where the run holds nothing for it to read.
 
 The timed path is the program's served entry, ``tracker.Tracker.step``:
 one client's frame in, one pose out. Each client is a host thread that
@@ -26,21 +28,26 @@ import sys
 import tempfile
 import threading
 import time
+import types
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import check, flops, generator, trace
+from bench import check, flops, generator, scopes, trace
 
 ROOT = Path(__file__).resolve().parent.parent
 SPEC = ROOT / "BENCHMARK.json"
 BENCH = ROOT / "bench"
-# A traced run's window: some 100 frames at 128x128, some 30 at 320x240.
-# Its per-layer readings are shares and per-frame means, which a few
-# seconds hold; a longer trace only costs time to write and to read.
-TRACE_SECONDS = 3.0
+# A traced run's window ends after this many frames, split evenly over
+# the clients, or at the run's seconds, whichever comes first: a count
+# of frames, so that a faster program keeps the trace as large. On a TPU
+# v5e the profiler keeps the device's events of about 970 frames of the
+# 128x128 step and drops the rest, which would read as idle (the run
+# fails where it did: ``check_trace_kept``); and writing the trace takes
+# some 0.2 to 0.3 s a frame, inside the 360 s a traced run may take.
+TRACE_FRAMES = 300
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,6 +58,7 @@ class Cell:
     traffic: dict
     end_to_end: list  # metric entries of BENCHMARK.json this cell reports
     per_layer: list
+    reference: types.ModuleType  # the configuration's hand model
 
 
 def _applies(metric: dict, cell: str) -> bool:
@@ -65,6 +73,23 @@ def metric_file(metric: str) -> Path:
     return BENCH / "metrics" / f"{metric}.py"
 
 
+def reference_module(config: dict) -> types.ModuleType:
+    """The module that the configuration's ``"reference"`` file is, as a
+    module of the ``bench`` package (``bench/reference.py`` is
+    ``bench.reference``), refused where its pose width or sphere count is
+    not the configuration's ``"hand"``."""
+    path = Path(config["reference"])
+    if path.suffix != ".py" or path.parts[0] != BENCH.name:
+        raise ValueError(f"{config['name']}: reference {path} is not a module under bench/")
+    module = importlib.import_module(".".join(path.with_suffix("").parts))
+    hand = config["hand"]
+    if (module.NUM_PARAMS, module.NUM_SPHERES) != (hand["num_params"], hand["num_spheres"]):
+        raise ValueError(
+            f"{config['name']}: hand {hand} but {module.__name__} has "
+            f"{module.NUM_PARAMS} parameters and {module.NUM_SPHERES} spheres")
+    return module
+
+
 def load_cell(name: str, spec_path: Path = SPEC) -> Cell:
     """The cell of that name, with its configuration and traffic loaded."""
     spec = json.loads(spec_path.read_text())
@@ -73,13 +98,15 @@ def load_cell(name: str, spec_path: Path = SPEC) -> Cell:
         raise KeyError(f"no workload {name!r} in {spec_path.name}; known: {sorted(cells)}")
     w = cells[name]
     configs = {c["name"]: c for c in spec["configs"]}
+    config = json.loads((spec_path.parent / configs[w["config"]]["file"]).read_text())
     return Cell(
         name=name,
         chips=w["chips"],
-        config=json.loads((spec_path.parent / configs[w["config"]]["file"]).read_text()),
+        config=config,
         traffic=json.loads(traffic_file(w["traffic"]).read_text()),
         end_to_end=[m for m in spec["end_to_end"] if _applies(m, name)],
         per_layer=[m for m in spec["per_layer"] if _applies(m, name)],
+        reference=reference_module(config),
     )
 
 
@@ -115,6 +142,7 @@ class Run:
     frame_flops: float
     peaks: dict
     trace: trace.TraceSummary | None = None
+    scopes: scopes.ScopeSummary | None = None  # of the program's scopes and spans
 
     @property
     def frames(self) -> int:
@@ -153,8 +181,9 @@ class _Client:
         self.indices.append(i)
         return submitted, done
 
-    def loop(self, first: int, t_end: float) -> None:
-        """Closed loop from frame ``first`` until a pose lands after t_end."""
+    def loop(self, first: int, t_end: float, count: int | None = None) -> None:
+        """Closed loop from frame ``first`` until a pose lands after t_end,
+        or until ``count`` frames are done."""
         k, last_done = first, None
         while True:
             submitted, done = self.track(k)
@@ -163,7 +192,7 @@ class _Client:
                 self.loop_gaps.append(submitted - last_done)
             last_done = done
             k += 1
-            if done >= t_end:
+            if done >= t_end or k - first == count:
                 self.last_done = done
                 return
 
@@ -199,15 +228,19 @@ def peak_memory_bytes(devices) -> int | None:
     return max(peaks) if peaks else None
 
 
-def compiled_memory(cfg, client: _Client) -> dict | None:
-    """What the compiled frame step holds on the device, by XLA's own
-    account: its arguments, outputs, temporaries and code. The peak of the
-    process also holds the benchmark's inputs; this is the tracker's part.
-    Taken after the window, from the persistent cache."""
+def compiled_step(cfg, client: _Client):
+    """The client's frame step compiled again after the window: a load
+    from the persistent cache of the program the window ran."""
     from repro.core import tracker
 
     frame = client.seq.frames[0]
-    compiled = tracker.make_track_frame(cfg).lower(client.trk.key, client.trk.h, frame).compile()
+    return tracker.make_track_frame(cfg).lower(client.trk.key, client.trk.h, frame).compile()
+
+
+def compiled_memory(compiled) -> dict | None:
+    """What the compiled frame step holds on the device, by XLA's own
+    account: its arguments, outputs, temporaries and code. The peak of the
+    process also holds the benchmark's inputs; this is the tracker's part."""
     m = compiled.memory_analysis()
     if m is None:
         return None
@@ -217,6 +250,31 @@ def compiled_memory(cfg, client: _Client) -> dict | None:
         "temp_bytes": m.temp_size_in_bytes,
         "code_bytes": m.generated_code_size_in_bytes,
     }
+
+
+def read_trace(trace_dir: Path, hlo_text: str):
+    """``trace.reduce`` and ``scopes.reduce`` of the trace the profiler
+    wrote under trace_dir, the program's scopes taken from the compiled
+    step's HLO text; (None, None) where it wrote none."""
+    from jax.profiler import ProfileData
+
+    path = trace.find_xplane(trace_dir)
+    if path is None:
+        return None, None
+    data = ProfileData.from_file(str(path))
+    planes = list(data.planes)
+    return trace.reduce(planes), scopes.reduce(planes, scopes.op_scopes(hlo_text))
+
+
+def check_trace_kept(scope_summary: scopes.ScopeSummary | None, frames: int) -> None:
+    """Raise where the trace lost frame steps of the window: the profiler
+    dropped device events, and every device reading would count them as
+    idle."""
+    if scope_summary is not None and scope_summary.step_runs < frames:
+        raise RuntimeError(
+            f"the trace kept the device operations of {scope_summary.step_runs} of the "
+            f"window's {frames} frame steps: the profiler dropped events, so its "
+            f"device readings would be wrong; trace fewer frames (TRACE_FRAMES)")
 
 
 def profile_options() -> jax.profiler.ProfileOptions:
@@ -250,7 +308,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     peaks = flops.peaks(dev.device_kind) if dev.platform == "tpu" else {}
     cfg = tracker_config(cell.config)
     log(f"setup: devices at {time.perf_counter() - process_start:.3f} s")
-    seqs = generator.build(cell.config["camera"], cell.traffic, seed)
+    seqs = generator.build(cell.reference, cell.config["camera"], cell.traffic, seed)
     log(f"setup: inputs at {time.perf_counter() - process_start:.3f} s")
     clients = [
         _Client(s, tracker.Tracker(cfg, h0=jnp.asarray(s.truth[0]), seed=s.seed))
@@ -261,8 +319,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
         for k in range(1, warm + 1):
             c.track(k)
 
-    if traced:
-        seconds = min(seconds, TRACE_SECONDS)
+    count = math.ceil(TRACE_FRAMES / len(clients)) if traced else None
     counter = CompileCounter()
     jax.monitoring.register_event_duration_secs_listener(counter)
     trace_dir = Path(tempfile.mkdtemp(prefix="bench_trace_")) if traced else None
@@ -274,22 +331,29 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
         setup_s = t_start - process_start
         with jax.profiler.TraceAnnotation("bench_window"):
             with concurrent.futures.ThreadPoolExecutor(len(clients)) as pool:
-                futures = [pool.submit(c.loop, warm + 1, t_start + seconds)
+                futures = [pool.submit(c.loop, warm + 1, t_start + seconds, count)
                            for c in clients]
                 for f in futures:
                     f.result()
         counter.active = False
         if traced:
+            t_stop = time.perf_counter()
             jax.profiler.stop_trace()
+            log(f"trace: written in {time.perf_counter() - t_stop:.3f} s")
     finally:
         jax.monitoring.unregister_event_duration_listener(counter)
 
     memory_peak = peak_memory_bytes(devices)
-    summary = None
+    step = compiled_step(cfg, clients[0])
+    step_memory = compiled_memory(step)
+    summary = scope_summary = None
     if traced:
-        path = trace.find_xplane(trace_dir)
-        summary = trace.read(path) if path else None
+        t_read = time.perf_counter()
+        summary, scope_summary = read_trace(trace_dir, step.as_text())
+        log(f"trace: read and reduced in {time.perf_counter() - t_read:.3f} s")
         shutil.rmtree(trace_dir, ignore_errors=True)
+        check_trace_kept(scope_summary, sum(len(c.latencies) for c in clients))
+    del step
 
     run = Run(
         setup_s=setup_s,
@@ -299,6 +363,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
         frame_flops=flops.frame_flops(cell.config),
         peaks=peaks,
         trace=summary,
+        scopes=scope_summary,
     )
     attempted = run.frames
     failed = sum(c.failed for c in clients)
@@ -309,13 +374,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
         "frame_ms_max": 1e3 * float(np.max(run.latencies_s)),
         "loop_gap_ms_max": 1e3 * max((g for c in clients for g in c.loop_gaps), default=0.0),
     }
-    step_memory = compiled_memory(cfg, clients[0])
     tracked = [c.tracked() for c in clients]
     for c in clients:
         c.trk = None  # free the program's state before the reference runs
     del clients
 
-    numbers, detail = check.readings(cell.config, tracked)
+    numbers, detail = check.readings(cell.reference, cell.config, tracked)
     ok, checks = check.compare(numbers, cell.config["limits"])
     unchecked = {k: v for k, v in numbers.items() if k not in checks}
     unchecked.update(detail)
@@ -344,6 +408,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
         device["window_s"] = summary.window_s
         result["breakdown"] = {"device_ops": summary.device_ops,
                                "idle_gaps": summary.idle_gaps}
+        if scope_summary is not None:
+            result["breakdown"]["device_scopes"] = scope_summary.device_scopes[:trace.TOP]
+            result["breakdown"]["program_gaps"] = scope_summary.program_gaps[:trace.TOP]
     result.update({
         "workload": cell.name,
         "seed": seed,

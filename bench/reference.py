@@ -17,6 +17,27 @@ The ``<ray, centre>`` product cancels badly in the hit test: (d.c)^2 and
 forms it in ``high`` (three bfloat16 passes), emulated with explicit
 bfloat16 rounding and float32 arithmetic, so that it reads the same on
 every backend.
+
+A configuration names its reference module in its ``"reference"`` key
+(``bench/reference.py`` for this one-hand model), and the harness, the
+traffic generator and the check know the hand only through these names
+of it, so a hand model of another pose width or sphere count is a module
+of its own:
+
+* ``NUM_PARAMS``, ``NUM_SPHERES``: the pose width and the spheres
+  rendered, as the configuration's ``"hand"`` states them;
+* ``trajectory(motion, num_frames, offset)``: (num_frames, NUM_PARAMS)
+  true poses of a traffic file's ``motion``;
+* ``camera_rays(camera)``: (P, 3) rays of a configuration's camera;
+* ``render(h, rays, dot)``: (P,) depth of pose h;
+* ``score(h, d_o, mask, rays, dot)``: E_D of pose h over ``mask``;
+* ``dot_high``: the ``<ray, centre>`` product one precision below the
+  configuration's, for ``dot`` (the control);
+* ``box_mask(d_o, h_prev, half_width)``: the bounding box B from the
+  previous pose;
+* ``recover_best(h, h_prev, smoothing)``: rows of the swarm's best
+  hypothesis behind rows of smoothed poses;
+* ``positions(h)``: (n, k, 3) positions of the k hands of n poses.
 """
 
 from __future__ import annotations
@@ -201,3 +222,71 @@ def score(h: jnp.ndarray, d_o: jnp.ndarray, mask: jnp.ndarray, rays: jnp.ndarray
     err = jnp.minimum(jnp.abs(render(h, rays, dot) - d_o), CLAMP_T)
     m = mask.astype(jnp.float32)
     return jnp.sum(err * m) / jnp.maximum(jnp.sum(m), 1.0)
+
+
+def box_mask(d_o: jnp.ndarray, h_prev: jnp.ndarray, half_width: float) -> jnp.ndarray:
+    """B from the previous pose: pixels observed within half_width of its depth."""
+    return bbox_mask(d_o, h_prev[2], half_width)
+
+
+def positions(h: np.ndarray) -> np.ndarray:
+    """(n, 1, 3) hand positions of poses h (n, NUM_PARAMS)."""
+    return h[:, None, :3]
+
+
+def trajectory(motion: dict, num_frames: int, offset: int) -> np.ndarray:
+    """(num_frames, 27) poses: frames offset .. offset + num_frames - 1.
+
+    The repository's default motion: hand at ``base_distance``, position
+    sweeps, wrist rotation about a wobbling axis, staggered finger curls,
+    with ``burst_speed`` times the speed for ``burst_length`` of every
+    ``burst_every`` frames."""
+    total = offset + num_frames
+    t = np.arange(total)
+    in_burst = (t % motion["burst_every"]) >= motion["burst_every"] - motion["burst_length"]
+    speed = np.where(in_burst, motion["burst_speed"], 1.0)
+    phase = (np.cumsum(speed) / 30.0)[offset:]  # seconds at 30 frames/s
+
+    amp = motion["position_amplitude"]
+    hs = np.zeros((num_frames, NUM_PARAMS), np.float64)
+    hs[:, 0] = amp * np.sin(2 * np.pi * 0.35 * phase)
+    hs[:, 1] = amp * 0.6 * np.sin(2 * np.pi * 0.23 * phase + 1.0)
+    hs[:, 2] = motion["base_distance"] + 0.04 * np.sin(2 * np.pi * 0.17 * phase)
+    ang = motion["rotation_amplitude"] * np.sin(2 * np.pi * 0.3 * phase)
+    axis = np.stack(
+        [np.sin(0.7 * phase), np.cos(0.9 * phase), 0.4 * np.ones_like(phase)], axis=-1
+    )
+    axis /= np.linalg.norm(axis, axis=-1, keepdims=True)
+    hs[:, 3] = np.cos(ang / 2)
+    hs[:, 4:7] = axis * np.sin(ang / 2)[:, None]
+    for f in range(5):
+        curl = 0.5 * motion["curl_amplitude"] * (
+            1 - np.cos(2 * np.pi * (0.4 + 0.05 * f) * phase + f)
+        )
+        base = 7 + 4 * f
+        hs[:, base + 1] = curl * 0.9
+        hs[:, base + 2] = curl
+        hs[:, base + 3] = curl * 0.7
+    return hs.astype(np.float32)
+
+
+def recover_best(h: np.ndarray, h_prev: np.ndarray, smoothing: float) -> np.ndarray:
+    """The swarm's best hypothesis behind a smoothed pose (rows of 27).
+
+    ``Tracker.step`` reports h = normalize((1 - s) * normalize(best) +
+    s * h_prev). Position and angles are affine in the best. The
+    quaternion q_h is a normalized a * u + b * q_prev with u the best's
+    unit quaternion, so u is lambda * q_h - b * q_prev with lambda the
+    root that makes |u| = a."""
+    h = h.astype(np.float64)
+    hp = h_prev.astype(np.float64)
+    a, b = np.float32(1.0 - smoothing), np.float32(smoothing)
+    best = (h - b * hp) / a
+    qh, qp = h[:, 3:7], hp[:, 3:7]
+    nh = np.sum(qh * qh, axis=1)
+    hp_dot = np.sum(qh * qp, axis=1)
+    disc = (b * hp_dot) ** 2 - nh * (b * b * np.sum(qp * qp, axis=1) - a * a)
+    lam = (b * hp_dot + np.sqrt(np.maximum(disc, 0.0))) / nh
+    u = lam[:, None] * qh - b * qp
+    best[:, 3:7] = u / np.linalg.norm(u, axis=1, keepdims=True)
+    return best.astype(np.float32)

@@ -20,6 +20,12 @@ same window (the ``bench_window`` spans):
   whole to the span at its midpoint as ``idle_gaps`` does: the host's
   round trip between two frame steps is one gap that crosses five spans.
 
+- ``step_runs``: the runs of the frame step whose device operations the
+  trace kept, the fewest over the devices. The profiler keeps a bounded
+  number of device events and drops the rest; a frame step it dropped
+  reads as idle in every reading above and in ``bench/trace.py``'s, so
+  the harness holds this count against the frames of the window.
+
 The trace names each operation but not its scope: that is in the
 compiled step's HLO text (``compiled.as_text()``), whose instruction
 names the trace's op names are.
@@ -61,6 +67,7 @@ class ScopeSummary:
     device_scopes: list  # [[scope or module, seconds], ...], largest first
     program_gaps: list  # [[open spans, seconds], ...], largest first
     clock_lag: list  # vertices [(device ns, ns to add to read it on the host's clock)]
+    step_runs: int  # frame steps with their device operations kept, fewest over devices
 
 
 def op_scopes(hlo_text: str) -> dict[str, str]:
@@ -107,6 +114,14 @@ def clock_lag(module_runs: list[tuple[float, int]], host_runs: dict) -> list[tup
     return [(t, max(lag for _, lag in points[bisect.bisect_left(times, t - LAG_WINDOW_NS):
                                              bisect.bisect_right(times, t + LAG_WINDOW_NS)]))
             for t in times]
+
+
+def _kept_runs(ops: list, modules: list, module: str) -> int:
+    """Runs of ``module`` (module events, by start) in which at least one
+    of ``ops`` starts."""
+    starts = sorted(s for _, s, _ in ops)
+    return sum(1 for name, s, e in modules if module_name(name) == module
+               and bisect.bisect_left(starts, s) < bisect.bisect_left(starts, e))
 
 
 def _segments(spans: list[tuple[str, float, float]]) -> list[tuple]:
@@ -205,6 +220,7 @@ def reduce(planes, scope_of: dict[str, str]) -> ScopeSummary | None:
 
     scope_ns: dict[str, float] = collections.Counter()
     busy_each = []
+    step_runs = min(_kept_runs(ops, modules, STEP_MODULE) for ops, modules in devices)
     for ops, modules in devices:
         starts = [s for _, s, _ in modules]
         labelled = []
@@ -241,5 +257,6 @@ def reduce(planes, scope_of: dict[str, str]) -> ScopeSummary | None:
         device_scopes=[[n, t * 1e-9] for n, t in scope_ns.most_common()],
         program_gaps=[[n, t] for n, t in idle.most_common()],
         clock_lag=lag,
+        step_runs=step_runs,
     )
 

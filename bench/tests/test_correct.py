@@ -9,6 +9,7 @@ the program's place; the faults are planted under the timed path.
 """
 
 import copy
+import dataclasses
 import time
 
 import jax
@@ -38,7 +39,7 @@ def _tiny(name):
     cfg = copy.deepcopy(cell.config)
     cfg["camera"] = {"width": 32, "height": 32, "fx": 27.5, "fy": 27.5, "cx": 15.5, "cy": 15.5}
     cfg["pso"] = {"num_particles": 32, "num_generations": 10}
-    return harness.Cell(cell.name, 1, cfg, cell.traffic, cell.end_to_end, cell.per_layer)
+    return dataclasses.replace(cell, chips=1, config=cfg)
 
 
 @pytest.fixture(scope="module")
@@ -68,7 +69,7 @@ def test_program_is_correct(tiny_cell):
 
 
 def test_control_is_not_correct(tiny_cell):
-    undo = faults.plant("control")
+    undo = faults.plant("control", tiny_cell.reference)
     try:
         r = _run(tiny_cell)
     finally:
@@ -79,7 +80,7 @@ def test_control_is_not_correct(tiny_cell):
 
 @pytest.mark.parametrize("fault", ["state_unchanged", "answer_altered", "half_the_pixels"])
 def test_fault_is_not_correct(tiny_cell, fault):
-    undo = faults.plant(fault)
+    undo = faults.plant(fault, tiny_cell.reference)
     try:
         r = _run(tiny_cell)
     finally:
@@ -97,7 +98,7 @@ def test_four_clients_are_correct(tiny_clients4):
 def test_one_client_slot_fault_is_not_correct(tiny_clients4):
     """One client of four scored against another's frame: that client's
     own numbers fail, though three quarters of the frames are sound."""
-    undo = faults.plant("client_slot")
+    undo = faults.plant("client_slot", tiny_clients4.reference)
     try:
         r = _run(tiny_clients4)
     finally:

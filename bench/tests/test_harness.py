@@ -1,19 +1,22 @@
 """The harness finds every part of a cell by name, and refuses to run
 anywhere but on the chip."""
 
+import hashlib
 import json
 import os
 import re
 import shutil
 import subprocess
+import time
 
 import numpy as np
 import pytest
 
-from bench import generator, harness, trace
+from bench import generator, harness, reference, scopes, trace
 
 SPEC = json.loads(harness.SPEC.read_text())
 CELLS = [w["name"] for w in SPEC["workloads"]]
+CONFIGS = [c["name"] for c in SPEC["configs"]]
 METRICS = SPEC["end_to_end"] + SPEC["per_layer"]
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -92,13 +95,56 @@ def test_unknown_cell_is_refused():
         harness.load_cell("no.such_cell")
 
 
+def _cell_of(config: str) -> str:
+    return next(w["name"] for w in SPEC["workloads"] if w["config"] == config)
+
+
+@pytest.mark.parametrize("config", CONFIGS)
+def test_the_configuration_s_reference_is_its_hand_model(config):
+    """``"reference": "bench/reference.py"`` is the module that the
+    generator, the check and the faults were written against."""
+    cell = harness.load_cell(_cell_of(config))
+    assert cell.config["reference"] == "bench/reference.py"
+    assert cell.reference is reference
+
+
+@pytest.mark.parametrize("config", CONFIGS)
+@pytest.mark.parametrize("hand", [{"num_params": 54, "num_spheres": 48},
+                                  {"num_params": 27, "num_spheres": 96}],
+                         ids=["pose_width", "spheres"])
+def test_a_hand_the_reference_does_not_have_is_refused_at_load(tmp_path, config, hand):
+    entry = next(c for c in SPEC["configs"] if c["name"] == config)
+    conf = json.loads((harness.ROOT / entry["file"]).read_text())
+    conf["hand"] = hand
+    (tmp_path / entry["file"]).parent.mkdir(parents=True)
+    (tmp_path / entry["file"]).write_text(json.dumps(conf))
+    shutil.copy(harness.SPEC, tmp_path / "BENCHMARK.json")
+    with pytest.raises(ValueError, match="parameters"):
+        harness.load_cell(_cell_of(config), tmp_path / "BENCHMARK.json")
+
+
+@pytest.mark.parametrize("path", ["bench/reference.json", "src/repro/core/handmodel.py"])
+def test_a_reference_outside_the_benchmark_s_modules_is_refused(path):
+    with pytest.raises(ValueError, match="not a module under bench"):
+        harness.reference_module({"name": "x", "reference": path,
+                                  "hand": {"num_params": 27, "num_spheres": 48}})
+
+
 def _fake_run(with_trace: bool) -> harness.Run:
     summary = trace.TraceSummary(window_s=2.0, busy_s=1.5, devices=1,
                                  device_ops=[["fusion", 1.5]], idle_gaps=[["step", 0.5]])
+    by_scope = scopes.ScopeSummary(
+        device_scopes=[["tracker.evaluate", 1.2], ["tracker.optimize", 0.2],
+                       ["jit__threefry_split", 0.1]],
+        program_gaps=[["step>tracker.wait", 0.2], ["fetch+step>tracker.key_split", 0.1],
+                      ["step>tracker.dispatch", 0.05], ["step>tracker.step", 0.05],
+                      ["host", 0.1]],
+        clock_lag=[], step_runs=100)
     return harness.Run(
         setup_s=7.5, client_frames=[40, 60], client_seconds=[2.0, 3.0],
         latencies_s=np.arange(1, 101) * 1e-3, frame_flops=1e9,
-        peaks={"flops_per_s_bf16": 1e12}, trace=summary if with_trace else None)
+        peaks={"flops_per_s_bf16": 1e12}, trace=summary if with_trace else None,
+        scopes=by_scope if with_trace else None)
 
 
 def test_readers_compute_from_the_run():
@@ -111,12 +157,54 @@ def test_readers_compute_from_the_run():
     assert read["device_idle_share"] == pytest.approx(25.0)
     assert read["step_device_ms"] == pytest.approx(15.0)
     assert read["frame_mfu"] == pytest.approx(100 * 1e9 * 50 / 1e12)
+    assert read["eval_device_ms"] == pytest.approx(1e3 * 1.2 / 100)
+    assert read["step_host_gap_ms"] == pytest.approx(1e3 * (0.2 + 0.1 + 0.05) / 100)
+
+
+def test_scope_readers_read_nothing_where_the_trace_lacks_their_scopes():
+    run = _fake_run(True)
+    run.scopes = scopes.ScopeSummary(device_scopes=[["unscoped", 1.5]],
+                                     program_gaps=[["step", 0.4], ["host", 0.1]], clock_lag=[],
+                                     step_runs=100)
+    for metric in ("eval_device_ms", "step_host_gap_ms"):
+        assert harness.load_reader(metric)(run) is None, metric
 
 
 def test_device_readers_read_nothing_without_a_trace():
     run = _fake_run(False)
     for m in SPEC["per_layer"]:
         assert harness.load_reader(m["name"])(run) is None, m["name"]
+
+
+class _Step:
+    """A tracker whose every step takes ``seconds``."""
+
+    def __init__(self, seconds):
+        self.seconds = seconds
+
+    def step(self, depth):
+        time.sleep(self.seconds)
+        return np.zeros(reference.NUM_PARAMS, np.float32), 0.5
+
+
+@pytest.mark.parametrize("count, seconds, frames", [
+    (None, 0.05, 1),  # untraced: until a pose lands after the window's end
+    (None, 0.0, None),  # untraced, the window open: past any count
+    (7, 0.0, 7),  # traced: the frame count ends it first
+    (7, 0.05, 1),  # traced: the window's end comes first
+])
+def test_a_client_loop_ends_at_the_window_or_its_count(count, seconds, frames):
+    seq = generator.ClientSequence(0, np.zeros((4, reference.NUM_PARAMS), np.float32),
+                                   np.zeros((4, 2, 2), np.float32))
+    client = harness._Client(seq, _Step(seconds))
+    t_end = time.perf_counter() + (0.02 if seconds else 0.3)
+    client.loop(1, t_end, count)
+    if frames is None:
+        assert len(client.latencies) > 7 and client.last_done >= t_end
+    else:
+        assert len(client.latencies) == frames
+    assert len(client.poses) == len(client.latencies) == len(client.loop_gaps) + 1
+    assert client.last_done > 0
 
 
 def _command(cwd, env):
@@ -150,9 +238,9 @@ def test_command_fails_without_the_program(tmp_path):
 @pytest.mark.parametrize("traffic", sorted({w["traffic"] for w in SPEC["workloads"]}))
 def test_traffic_is_deterministic_in_the_seed(traffic):
     mix = json.loads(harness.traffic_file(traffic).read_text())
-    a = generator.build(TINY, mix, 2**31 + 11)
-    b = generator.build(TINY, mix, 2**31 + 11)
-    c = generator.build(TINY, mix, 2**31 + 12)
+    a = generator.build(reference, TINY, mix, 2**31 + 11)
+    b = generator.build(reference, TINY, mix, 2**31 + 11)
+    c = generator.build(reference, TINY, mix, 2**31 + 12)
     assert len(a) == mix["clients"]
     assert a[0].frames.shape == (mix["sequence_frames"], TINY["height"], TINY["width"])
     for x, y, z in zip(a, b, c):
@@ -162,9 +250,30 @@ def test_traffic_is_deterministic_in_the_seed(traffic):
         assert not np.array_equal(x.frames, z.frames)
 
 
+# sha256 over each client's truth and frames, in turn, of the sequences
+# that ``generator.build`` made before the hand model came from the
+# configuration's reference module, on the CPU at TINY, seed 2**31 + 11
+DIGESTS_BEFORE = {
+    "solo": "407dd455459eb056487400c33e11def5445b2ccbeb42f9d2d7ef3f29618d9057",
+    "clients4": "fc4be652b42f9331c453d0ddf4a627cef9da0711f3d2cb91925d0ae5a3100233",
+}
+
+
+@pytest.mark.parametrize("traffic", sorted(DIGESTS_BEFORE))
+def test_traffic_through_the_cell_s_reference_is_bit_identical(traffic):
+    cell = harness.load_cell(next(w["name"] for w in SPEC["workloads"]
+                                  if w["traffic"] == traffic))
+    mix = json.loads(harness.traffic_file(traffic).read_text())
+    digest = hashlib.sha256()
+    for seq in generator.build(cell.reference, TINY, mix, 2**31 + 11):
+        digest.update(seq.truth.tobytes())
+        digest.update(seq.frames.tobytes())
+    assert digest.hexdigest() == DIGESTS_BEFORE[traffic]
+
+
 def test_sequence_plays_back_without_a_jump():
     mix = json.loads(harness.traffic_file("solo").read_text())
-    truth = generator.trajectory(mix["motion"], 50, offset=3)
+    truth = reference.trajectory(mix["motion"], 50, offset=3)
     seq = generator.ClientSequence(0, truth, np.zeros((50, 1, 1), np.float32))
     path = np.stack([truth[seq.index(k)] for k in range(200)])
     steps = np.abs(np.diff(path[:, :3], axis=0)).max()
@@ -174,7 +283,7 @@ def test_sequence_plays_back_without_a_jump():
 
 def test_bursts_recur_every_period():
     mix = json.loads(harness.traffic_file("solo").read_text())["motion"]
-    truth = generator.trajectory(mix, 3 * mix["burst_every"], offset=0)
+    truth = reference.trajectory(mix, 3 * mix["burst_every"], offset=0)
     speed = np.linalg.norm(np.diff(truth[:, :3], axis=0), axis=1)
     every, length = mix["burst_every"], mix["burst_length"]
     fast = [speed[p * every + every - length:(p + 1) * every - 1].mean() for p in range(3)]

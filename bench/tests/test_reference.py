@@ -1,11 +1,14 @@
 """The benchmark's own reference agrees with the program at a small camera."""
 
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import check, generator, reference
+from bench import check, generator, harness, reference
+from bench.tests import two_hands
 from repro.core import handmodel, objective, tracker
 from repro.core.camera import Camera
 
@@ -20,7 +23,7 @@ MOTION = {
 @pytest.fixture(scope="module")
 def poses():
     """Truth poses with a random perturbation: curls, rotations, offsets."""
-    truth = generator.trajectory(MOTION, 40, offset=7)[::5]
+    truth = reference.trajectory(MOTION, 40, offset=7)[::5]
     rng = np.random.default_rng(3)
     return (truth + rng.normal(0, 0.02, truth.shape)).astype(np.float32)
 
@@ -75,7 +78,7 @@ def test_recover_best_inverts_the_refine_stage(poses):
         return tracker.stage_refine(cfg, State, p)[0]
 
     h = np.stack([np.asarray(refine(b, p)) for b, p in zip(best, prev)])
-    got = check.recover_best(h, np.asarray(prev), cfg.smoothing)
+    got = reference.recover_best(h, np.asarray(prev), cfg.smoothing)
     np.testing.assert_allclose(got, np.asarray(best), atol=5e-7)
 
 
@@ -84,8 +87,8 @@ def test_readings_hold_the_worst_client():
     gap is that client's, not a median diluted by the sound client."""
     cfg = tracker.TrackerConfig(camera=Camera(**CAMERA))
     config = {"camera": CAMERA, "tracker": {"smoothing": cfg.smoothing, "bbox_half_width": 0.25}}
-    truth = generator.trajectory(MOTION, 6, offset=2)
-    frames = generator.render(CAMERA, 0.002, truth, 5).reshape(6, CAMERA["height"], -1)
+    truth = reference.trajectory(MOTION, 6, offset=2)
+    frames = generator.render(reference, CAMERA, 0.002, truth, 5).reshape(6, CAMERA["height"], -1)
 
     class State:
         global_best_score = 0.0
@@ -95,12 +98,57 @@ def test_readings_hold_the_worst_client():
         State.global_best = jax.device_get(handmodel.normalize_configuration(jnp.asarray(b)))
         poses.append(np.asarray(tracker.stage_refine(cfg, State, jnp.asarray(poses[-1]))[0]))
     poses = np.stack(poses).astype(np.float32)
-    best = check.recover_best(poses[1:], poses[:-1], cfg.smoothing)
-    scores = check.reference_scores(config, best, frames, poses[:-1, 2])
+    best = reference.recover_best(poses[1:], poses[:-1], cfg.smoothing)
+    scores = check.reference_scores(reference, config, best, frames, poses[:-1])
     sound = {"poses": poses, "scores": scores.astype(np.float32), "frames": frames,
              "truth": truth}
     off = dict(sound, scores=(scores + 1e-3).astype(np.float32))
-    compared, unchecked = check.readings(config, [sound, off])
+    compared, unchecked = check.readings(reference, config, [sound, off])
     assert unchecked["client_score_gap_median"][0] < 1e-6
     assert compared["score_gap_median"] == pytest.approx(1e-3, rel=1e-3)
     assert compared["pos_err_mean_m"] == max(unchecked["client_pos_err_mean_m"])
+    # what these inputs read before the hand model came from the
+    # configuration's reference module: the same numbers, to the bit
+    assert compared == READINGS_BEFORE
+    assert unchecked == UNCHECKED_BEFORE
+
+
+READINGS_BEFORE = {
+    "score_gap_median": 0.0010000000474974513, "pos_err_mean_m": 0.000610058254096657,
+    "score_over_truth_mean": 0.0009999332833103836,
+    "score_over_truth_median": 0.0009999626781791449,
+}
+UNCHECKED_BEFORE = {
+    "score_gap_p90": 0.0010000000474974513, "score_gap_p99": 0.0010000001511070878,
+    "score_gap_max": 0.0010000001639127731,
+    "client_score_gap_median": [0.0, 0.0010000000474974513],
+    "client_pos_err_mean_m": [0.000610058254096657, 0.000610058254096657],
+    "client_score_over_truth_mean": [-6.67447845141093e-08, 0.0009999332833103836],
+    "client_score_over_truth_median": [-3.742752596735954e-08, 0.0009999626781791449],
+}
+
+
+def test_a_hand_model_of_another_width_needs_no_harness_edit():
+    """A configuration naming the 54-parameter, 96-sphere stand-in loads
+    it, and the generator and the check run through it: the truth fed
+    back as the tracked poses, with no smoothing, reads no gap at all."""
+    config = {"name": "two_hands", "reference": "bench/tests/two_hands.py",
+              "hand": {"num_params": 54, "num_spheres": 96},
+              "camera": {"width": 32, "height": 32, "fx": 27.5, "fy": 27.5,
+                         "cx": 15.5, "cy": 15.5},
+              "tracker": {"smoothing": 0.0, "bbox_half_width": 0.25}}
+    ref = harness.reference_module(config)
+    assert ref is two_hands
+    traffic = json.loads(harness.traffic_file("solo").read_text())
+    (seq,) = generator.build(ref, config["camera"], dict(traffic, sequence_frames=40),
+                             2**31 + 3)
+    assert seq.truth.shape == (40, 54) and seq.frames.shape == (40, 32, 32)
+    one_hand = generator.render(reference, config["camera"], 0.0, seq.truth[:, :27], 1)
+    assert (seq.frames < 1.0).sum() > 1.5 * (one_hand < 1.0).sum()  # both hands in view
+    poses = np.concatenate([seq.truth[:1], seq.truth])
+    scores = check.reference_scores(ref, config, seq.truth, seq.frames, poses[:-1])
+    tracked = {"poses": poses, "scores": scores.astype(np.float32),
+               "frames": seq.frames, "truth": seq.truth}
+    compared, _ = check.readings(ref, config, [tracked])
+    assert compared["pos_err_mean_m"] == 0.0
+    assert compared["score_gap_median"] == 0.0

@@ -138,6 +138,14 @@ def test_an_op_outside_any_module_is_unscoped():
     assert dict(s.device_scopes) == pytest.approx({"unscoped": 100e-9})
 
 
+def test_step_runs_count_the_frame_steps_whose_operations_were_kept():
+    assert scopes.reduce([HOST, tpu(OPS, MODULES)], SCOPE_OF).step_runs == 1
+    # a second frame step whose operations the profiler dropped
+    dropped = MODULES + [ev("jit_track_frame(22)", 1100, 1700)]
+    assert scopes.reduce([HOST, tpu(OPS, dropped)], SCOPE_OF).step_runs == 1
+    assert scopes.reduce([HOST, tpu(OPS, MODULES[:1])], SCOPE_OF).step_runs == 0
+
+
 @pytest.mark.parametrize("planes", [
     [HOST],  # no device ran anything
     [tpu(OPS, MODULES)],  # no benchmark window
@@ -247,3 +255,64 @@ def test_the_lag_follows_the_tightest_runs_nearby_and_skips_queued_ones():
     assert scopes.clock_lag(module_runs, host_runs) == pytest.approx(
         [(0.0, 1.2 * ms), (0.4e9, 1.1 * ms), (0.48e9, 1.1 * ms)])
     assert scopes.clock_lag([(300.0, 7)], {8: 0.0}) == []
+
+
+@pytest.mark.parametrize("metric, value", [
+    ("eval_device_ms", lambda s: dict(s.device_scopes)["tracker.evaluate"]),
+    ("step_host_gap_ms", lambda s: sum(t for label, t in s.program_gaps if label in (
+        "step>tracker.key_split", "step>tracker.dispatch", "step>tracker.wait"))),
+])
+def test_readers_read_the_recorded_trace_as_reduced(recorded, metric, value):
+    """Each reader gives, per frame of the window, what ``scopes.reduce``
+    read from the recorded trace; and nothing without the scopes."""
+    from bench import harness
+
+    _, planes, s, today = recorded
+    host = _events(planes, lambda n: n.startswith("/host:"))
+    (lo, hi), = [(a, b) for n, a, b in host if n == trace.WINDOW_SPAN]
+    frames = sum(1 for n, a, _ in host if n == "tracker.step" and lo <= a < hi)
+    assert frames >= 2
+    run = harness.Run(setup_s=1.0, client_frames=[frames], client_seconds=[today.window_s],
+                      latencies_s=np.ones(frames), frame_flops=1.0, peaks={},
+                      trace=today, scopes=s)
+    read = harness.load_reader(metric)
+    assert value(s) > 0
+    assert read(run) == pytest.approx(1e3 * value(s) / frames, rel=1e-12)
+    run.scopes = None
+    assert read(run) is None
+
+
+def _kept(planes, keep):
+    """The planes with only the device events for which ``keep(line name,
+    start)`` holds, as a profiler that dropped the rest wrote them."""
+    return [NS(name=p.name, lines=[
+        NS(name=line.name, events=[e for e in line.events if not trace.DEVICE_PLANE.match(p.name)
+                                   or keep(line.name, e.start_ns)])
+        for line in p.lines]) for p in planes]
+
+
+@pytest.mark.parametrize("keep", [
+    lambda line, start, cut: line != trace.OPS_LINE or start < cut,
+    lambda line, start, cut: start < cut,
+    lambda line, start, cut: line != trace.OPS_LINE or start >= cut,
+], ids=["later_ops", "later_ops_and_modules", "earlier_ops"])
+def test_a_trace_that_dropped_frame_steps_fails_the_run(recorded, keep):
+    """The recorded trace kept every frame step of its window; cut at its
+    sixth step's start, it holds fewer than the frames the run counted,
+    and ``check_trace_kept`` refuses it."""
+    from bench import harness
+
+    _, planes, s, _ = recorded
+    host = _events(planes, lambda n: n.startswith("/host:"))
+    (lo, hi), = [(a, b) for n, a, b in host if n == trace.WINDOW_SPAN]
+    frames = sum(1 for n, a, _ in host if n == "tracker.step" and lo <= a < hi)
+    assert s.step_runs == frames
+    harness.check_trace_kept(s, frames)
+    steps = sorted(a for n, a, _ in _events(planes, trace.DEVICE_PLANE.match, scopes.MODULES_LINE)
+                   if scopes.module_name(n) == scopes.STEP_MODULE)
+    hlo = gzip.decompress(RECORDED_HLO.read_bytes()).decode()
+    cut = scopes.reduce(_kept(planes, lambda line, start: keep(line, start, steps[5])),
+                        scopes.op_scopes(hlo))
+    assert 0 < cut.step_runs < frames
+    with pytest.raises(RuntimeError, match="dropped events"):
+        harness.check_trace_kept(cut, frames)

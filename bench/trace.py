@@ -154,12 +154,6 @@ def reduce(planes) -> TraceSummary | None:
     )
 
 
-def read(path: Path) -> TraceSummary | None:
-    from jax.profiler import ProfileData
-
-    return reduce(ProfileData.from_file(str(path)).planes)
-
-
 def find_xplane(log_dir: Path) -> Path | None:
     """The newest ``.xplane.pb`` that ``jax.profiler`` wrote under log_dir."""
     found = sorted(Path(log_dir).rglob("*.xplane.pb"), key=lambda p: p.stat().st_mtime)
