@@ -1,6 +1,6 @@
 """27-DoF kinematic hand model (paper §3.1, "Hand model").
 
-The hand configuration vector ``h`` has 27 kinematic parameters:
+The configuration vector ``h`` of one hand has 27 kinematic parameters:
 
 * ``h[0:3]``   — 3D location of the hand root (palm center), meters.
 * ``h[3:7]``   — 3D orientation as a unit quaternion ``(w, x, y, z)``
@@ -15,6 +15,13 @@ quadric primitives. We use spheres placed along each bone (a capsule
 approximated by ``SPHERES_PER_BONE`` spheres) plus a palm slab of spheres,
 because analytic sphere depth is pure FMA math — the TPU-idiomatic
 equivalent of the paper's CUDA rasterizer (see DESIGN.md §2).
+
+A pose of k hands is k such vectors side by side, ``k * NUM_PARAMS``
+wide: one hand is the right hand described here; two are a left and a
+right hand, in that order, as Oikonomidis, Kyriazis and Argyros track
+two strongly interacting hands (CVPR, 2012). The left hand is the right
+hand's geometry mirrored in its local x. Every function below that
+takes a pose acts hand by hand.
 
 Everything here is pure JAX and differentiable (PSO does not need
 gradients — the paper stresses that — but differentiability is free and
@@ -33,7 +40,7 @@ import jax.numpy as jnp
 # Parameter layout
 # ---------------------------------------------------------------------------
 
-NUM_PARAMS = 27
+NUM_PARAMS = 27  # of one hand
 POS_SLICE = slice(0, 3)
 QUAT_SLICE = slice(3, 7)
 ANGLES_SLICE = slice(7, 27)
@@ -91,6 +98,32 @@ NUM_FINGER_SPHERES = (
 NUM_SPHERES_RAW = NUM_PALM_SPHERES + NUM_FINGER_SPHERES + len(FINGER_NAMES)
 # pad to a multiple of 8 so kernel tiles stay aligned
 NUM_SPHERES = ((NUM_SPHERES_RAW + 7) // 8) * 8
+
+# Whether each hand of a pose of k hands is a left hand, by k.
+_LEFT = {1: (False,), 2: (True, False)}
+
+
+def num_hands(h: jnp.ndarray) -> int:
+    """The number of hands of pose(s) h (..., k * NUM_PARAMS): 1 or 2."""
+    k, rest = divmod(h.shape[-1], NUM_PARAMS)
+    if rest or k not in _LEFT:
+        raise ValueError(f"a pose holds 1 or 2 hands of {NUM_PARAMS} parameters, "
+                         f"not {h.shape[-1]} parameters")
+    return k
+
+
+def hands(h: jnp.ndarray) -> list:
+    """The (..., NUM_PARAMS) parameters of each hand of pose(s) h, in order."""
+    k = num_hands(h)
+    if k == 1:
+        return [h]
+    return [h[..., i * NUM_PARAMS:(i + 1) * NUM_PARAMS] for i in range(k)]
+
+
+def _join(parts: list, axis: int = -1) -> jnp.ndarray:
+    """The hands' parts side by side; one hand's part as it is."""
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=axis)
+
 
 # Per-dimension articulation limits (radians), used both to clamp FK inputs
 # and as PSO search bounds.
@@ -281,11 +314,13 @@ def hand_spheres_local(angles: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return centers, radii
 
 
-def hand_spheres_world(h: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Sphere primitives in camera/world coordinates for configuration h.
+def hand_spheres_world(h: jnp.ndarray, left: bool = False
+                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Sphere primitives in camera/world coordinates for one hand.
 
     Args:
-      h: (27,) hand configuration.
+      h: (NUM_PARAMS,) configuration of one hand.
+      left: a left hand: the local sphere centres mirrored in x.
 
     Returns:
       centers (NUM_SPHERES, 3) in camera frame, radii (NUM_SPHERES,).
@@ -294,18 +329,24 @@ def hand_spheres_world(h: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     quat = quat_normalize(h[QUAT_SLICE])
     angles = h[ANGLES_SLICE]
     centers_l, radii = hand_spheres_local(angles)
+    if left:
+        centers_l = centers_l * jnp.asarray([-1.0, 1.0, 1.0], dtype=centers_l.dtype)
     centers_w = quat_rotate(quat[None, :], centers_l) + pos[None, :]
     return centers_w, radii
 
 
 def pack_spheres(h: jnp.ndarray) -> jnp.ndarray:
-    """(NUM_SPHERES, 4) packed [cx, cy, cz, r] — the kernel input format."""
-    c, r = hand_spheres_world(h)
-    return jnp.concatenate([c, r[:, None]], axis=-1)
+    """(k * NUM_SPHERES, 4) packed [cx, cy, cz, r] of the k hands of pose h
+    (k * NUM_PARAMS,), hand by hand — the kernel input format."""
+    packed = []
+    for hand, left in zip(hands(h), _LEFT[num_hands(h)]):
+        c, r = hand_spheres_world(hand, left)
+        packed.append(jnp.concatenate([c, r[:, None]], axis=-1))
+    return _join(packed, axis=0)
 
 
 def default_pose(distance: float = 0.55) -> jnp.ndarray:
-    """A neutral open hand facing the camera at `distance` meters."""
+    """A neutral open (right) hand facing the camera at `distance` meters."""
     h = jnp.zeros((NUM_PARAMS,), dtype=jnp.float32)
     h = h.at[2].set(distance)
     h = h.at[3].set(1.0)  # identity quaternion
@@ -314,32 +355,34 @@ def default_pose(distance: float = 0.55) -> jnp.ndarray:
 
 def parameter_lower_bounds(center: jnp.ndarray, pos_range: float = 0.12,
                            quat_range: float = 0.25) -> jnp.ndarray:
-    """PSO lower bounds: a box around `center` (the previous-frame solution).
+    """PSO lower bounds: a box around `center` (the previous-frame solution),
+    hand by hand.
 
     The paper: "particles are initialized around the solution of the
     previous frame. The space around that solution is made large enough to
     include the current frame estimation."
     """
-    lo = jnp.concatenate([
-        center[POS_SLICE] - pos_range,
-        center[QUAT_SLICE] - quat_range,
-        jnp.maximum(center[ANGLES_SLICE] - 0.6, angle_lower_bounds()),
-    ])
-    return lo
+    return _join([jnp.concatenate([
+        c[POS_SLICE] - pos_range,
+        c[QUAT_SLICE] - quat_range,
+        jnp.maximum(c[ANGLES_SLICE] - 0.6, angle_lower_bounds()),
+    ]) for c in hands(center)])
 
 
 def parameter_upper_bounds(center: jnp.ndarray, pos_range: float = 0.12,
                            quat_range: float = 0.25) -> jnp.ndarray:
-    hi = jnp.concatenate([
-        center[POS_SLICE] + pos_range,
-        center[QUAT_SLICE] + quat_range,
-        jnp.minimum(center[ANGLES_SLICE] + 0.6, angle_upper_bounds()),
-    ])
-    return hi
+    return _join([jnp.concatenate([
+        c[POS_SLICE] + pos_range,
+        c[QUAT_SLICE] + quat_range,
+        jnp.minimum(c[ANGLES_SLICE] + 0.6, angle_upper_bounds()),
+    ]) for c in hands(center)])
 
 
 def normalize_configuration(h: jnp.ndarray) -> jnp.ndarray:
-    """Renormalize the quaternion block (PSO moves particles off the
+    """Renormalize each hand's quaternion block (PSO moves particles off the
     unit-quaternion manifold; this projects back)."""
-    q = quat_normalize(h[..., QUAT_SLICE])
-    return jnp.concatenate([h[..., POS_SLICE], q, h[..., ANGLES_SLICE]], axis=-1)
+    out = []
+    for c in hands(h):
+        q = quat_normalize(c[..., QUAT_SLICE])
+        out.append(jnp.concatenate([c[..., POS_SLICE], q, c[..., ANGLES_SLICE]], axis=-1))
+    return _join(out)

@@ -14,6 +14,16 @@ fixed by the frame's pixel count (``box_ladder``, ``box_rung``) with
 K >= |B|. Every pixel outside B adds an exact zero to the sum, so this
 changes only the order in which the nonzero terms are added.
 
+A hypothesis of two hands (Oikonomidis et al., CVPR, 2012) renders both
+hands' spheres into one depth map, so the nearer hand occludes the
+other, and its score adds a penalty for the hands passing into each
+other:
+
+    E(h) = E_D(h) + λ · kc(h),
+    kc(h) = sum_{p in S_L, q in S_R} max(0, r_p + r_q - |c_p - c_q|)
+
+over the left hand's and the right hand's spheres (``penalized``).
+
 This module is the *reference* (pure jnp) implementation; the Pallas
 kernel in ``repro.kernels.render_score`` computes the same quantity with
 explicit VMEM tiling, and ``repro.kernels.ref`` re-exports these functions
@@ -31,6 +41,9 @@ from repro.core import handmodel
 from repro.core.camera import BACKGROUND_DEPTH, Camera
 
 CLAMP_T = 0.30  # meters — the paper sets T = 30 cm.
+# λ of the two-hand objective, per metre of summed penetration depth: an
+# assumed value, not the paper's.
+COLLIDE_WEIGHT = 1.0
 
 
 def sphere_depth(rays: jnp.ndarray, spheres: jnp.ndarray) -> jnp.ndarray:
@@ -141,17 +154,46 @@ def box_rung(n, num_pixels: int):
     return ladder[jnp.sum(ladder < n)]
 
 
+def interpenetration(spheres: jnp.ndarray) -> jnp.ndarray:
+    """kc of packed two-hand spheres (..., 2 * NUM_SPHERES, 4) -> (...,).
+
+    The sum, over every pair of a left-hand sphere p and a right-hand
+    sphere q, of the depth max(0, r_p + r_q - |c_p - c_q|) by which they
+    overlap. Padding spheres are left out: they have no radius, and sit
+    at their hand's root, where a sphere of the other hand could hold
+    them. Element-wise differences, so no matmul precision enters it."""
+    with jax.named_scope("tracker.collide"):
+        s, n = handmodel.NUM_SPHERES, handmodel.NUM_SPHERES_RAW
+        left = spheres[..., :n, None, :]
+        right = spheres[..., None, s:s + n, :]
+        diff = left[..., :3] - right[..., :3]
+        dist = jnp.sqrt(jnp.sum(diff * diff, axis=-1))
+        depth = jnp.maximum(left[..., 3] + right[..., 3] - dist, 0.0)
+        return jnp.sum(depth, axis=(-2, -1))
+
+
+def penalized(scores: jnp.ndarray, spheres: jnp.ndarray) -> jnp.ndarray:
+    """E of a population from its E_D ``scores`` (N,) and packed spheres
+    (N, k * NUM_SPHERES, 4): E_D + λ · kc for two hands, E_D for one."""
+    if spheres.shape[-2] == handmodel.NUM_SPHERES:
+        return scores
+    return scores + COLLIDE_WEIGHT * interpenetration(spheres)
+
+
 def batched_objective(
     hs: jnp.ndarray,
     d_o: jnp.ndarray,
     camera: Camera,
     mask: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
-    """Vectorized E_D over a particle population. hs: (N, 27) -> (N,).
+    """Vectorized E over a particle population. hs: (N, k * 27) -> (N,).
 
     This is the GPGPU-parallel evaluation the paper offloads; the Pallas
-    kernel path (repro.kernels.ops.render_score) computes the same thing
+    kernel path (repro.kernels.ops.render_score) computes the same E_D
     with explicit tiling and is swapped in by the tracker when enabled.
+    Each hypothesis's k hands are rendered in one pass over their
+    k * NUM_SPHERES spheres: the nearest hit over all of them is the
+    occlusion. With two hands, λ · kc is added (``penalized``).
 
     With a mask, only B's pixels are rendered. A stable partition puts
     B's n pixels first; the rays and d_o are gathered into that order, and
@@ -165,7 +207,8 @@ def batched_objective(
     the frame, so XLA runs them once a frame, outside the PSO loop.
     """
     if mask is None:
-        return jax.vmap(lambda h: objective(h, d_o, camera))(hs)
+        return penalized(jax.vmap(lambda h: objective(h, d_o, camera))(hs),
+                         jax.vmap(handmodel.pack_spheres)(hs))
     inside = mask.reshape(-1).astype(bool)
     num_pixels = inside.shape[0]
     n = jnp.sum(inside, dtype=jnp.int32)
@@ -197,9 +240,9 @@ def batched_objective(
         # Called outside a trace, the count is on the host: run that rung
         # alone and op by op, as the rest of the call runs, rather than
         # compiling every rung for one call.
-        return rung(int(rendered))(spheres)
+        return penalized(rung(int(rendered))(spheres), spheres)
     index = jnp.sum(jnp.asarray(ladder) < rendered)
-    return jax.lax.switch(index, [rung(k) for k in ladder], spheres)
+    return penalized(jax.lax.switch(index, [rung(k) for k in ladder], spheres), spheres)
 
 
 def bounding_box_mask(

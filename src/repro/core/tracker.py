@@ -56,9 +56,10 @@ def _make_eval_fn(
         def eval_fn(hs: jnp.ndarray) -> jnp.ndarray:
             with jax.named_scope("tracker.evaluate"):
                 spheres = jax.vmap(handmodel.pack_spheres)(hs)
-                return kernel_ops.render_score(
+                scores = kernel_ops.render_score(
                     spheres, rays, d_o.reshape(-1), mask.reshape(-1)
                 )
+                return objective.penalized(scores, spheres)
 
         return eval_fn
 
@@ -77,8 +78,11 @@ def _make_eval_fn(
 def stage_preprocess(
     cfg: TrackerConfig, h_prev: jnp.ndarray, depth: jnp.ndarray
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Stage 1: ROI/bounding-box extraction. Returns (depth, mask)."""
-    mask = objective.bounding_box_mask(depth, h_prev[2], cfg.bbox_half_width)
+    """Stage 1: ROI/bounding-box extraction. Returns (depth, mask): B is
+    the union of each hand's depth band around its own previous depth."""
+    mask = functools.reduce(jnp.logical_or, [
+        objective.bounding_box_mask(depth, hand[2], cfg.bbox_half_width)
+        for hand in handmodel.hands(h_prev)])
     return depth, mask
 
 
@@ -234,8 +238,9 @@ class Tracker:
 # ---------------------------------------------------------------------------
 
 
-def _eval_flops_per_generation(cfg: TrackerConfig) -> float:
-    """Analytic FLOP count of one population evaluation.
+def _eval_flops_per_generation(cfg: TrackerConfig, hands: int = 1) -> float:
+    """Analytic FLOP count of one population evaluation of poses of
+    ``hands`` hands.
 
     Per (particle, pixel, sphere): dot products + discriminant + sqrt
     ~= 14 fused ops; the min-reduction and scoring add ~3 per (particle,
@@ -243,22 +248,26 @@ def _eval_flops_per_generation(cfg: TrackerConfig) -> float:
     kernel evaluates."""
     n = cfg.pso.num_particles
     p = cfg.camera.num_pixels
-    s = handmodel.NUM_SPHERES
-    fk_flops = n * 600.0 * 5  # forward kinematics per particle (tiny)
+    s = hands * handmodel.NUM_SPHERES
+    fk_flops = n * hands * 600.0 * 5  # forward kinematics per particle (tiny)
     return n * p * (s * 14.0 + 3.0) + fk_flops
 
 
 def build_staged(
-    cfg: TrackerConfig, frame_nbytes: Optional[int] = None
+    cfg: TrackerConfig, frame_nbytes: Optional[int] = None,
+    h0: Optional[jnp.ndarray] = None,
 ) -> StagedComputation:
-    """The Fig. 2 pipeline with measured byte sizes and analytic FLOPs.
+    """The Fig. 2 pipeline with measured byte sizes and analytic FLOPs,
+    for poses as wide as ``h0`` (one hand where it is not given, as in
+    ``Tracker``).
 
     ``frame_nbytes`` overrides the size of the sensor frame that crosses
     the network (the paper ships RGB + depth at sensor resolution while
     hypotheses are rendered at a reduced working resolution; see
     sim/hardware.py PAPER_FRAME_BYTES)."""
     cam = cfg.camera
-    n, d = cfg.pso.num_particles, handmodel.NUM_PARAMS
+    hands = handmodel.num_hands(h0) if h0 is not None else 1
+    n, d = cfg.pso.num_particles, hands * handmodel.NUM_PARAMS
     frame_bytes = (
         frame_nbytes if frame_nbytes is not None else cam.num_pixels * 4
     )
@@ -270,7 +279,7 @@ def build_staged(
     swarm_bytes = (3 * n * d + 2 * n + d + 1 + 2) * 4  # SwarmState payload
 
     gens = cfg.pso.num_generations
-    eval_flops = _eval_flops_per_generation(cfg)
+    eval_flops = _eval_flops_per_generation(cfg, hands)
 
     sources = (
         DataItem("frame_depth", frame_bytes, CLIENT),

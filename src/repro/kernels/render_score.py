@@ -36,7 +36,8 @@ kernel.
 VMEM budget at the default BN=8, BP=512, S=48, f32: the sphere tile
 pads its 4-wide lane dim to 128 (8*48*128*4 B = 192 KiB), and each
 (BN, S, BP) intermediate is 8*48*512*4 B = 768 KiB — a few MiB in all,
-well inside the 16 MiB scoped VMEM.
+well inside the 16 MiB scoped VMEM. A hypothesis of two hands has
+S=96, which doubles both.
 """
 
 from __future__ import annotations

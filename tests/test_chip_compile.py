@@ -87,3 +87,21 @@ def test_paper_track_frame_compiles(one_chip, compiled_kernels, use_kernel):
     assert ("tpu_custom_call" in text) == use_kernel
     # the kernel keeps its own name in the step, so a trace finds it
     assert bool(re.search(r"%render_score[.\d]* = [^\n]*custom-call", text)) == use_kernel
+
+
+@pytest.mark.parametrize("use_kernel", [False, True], ids=["jnp", "kernel"])
+def test_two_hand_track_frame_compiles(one_chip, compiled_kernels, use_kernel):
+    """A pose of two hands: 96 spheres a hypothesis on both paths, and the
+    interpenetration penalty under its own scope."""
+    step = tracker.make_track_frame(
+        dataclasses.replace(CFG, use_kernel=use_kernel)
+    )
+    cam = CFG.camera
+    compiled = step.lower(
+        _spec(one_chip, 2, dtype=jnp.uint32),
+        _spec(one_chip, 2 * handmodel.NUM_PARAMS),
+        _spec(one_chip, cam.height, cam.width),
+    ).compile()
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == use_kernel
+    assert "tracker.collide" in text

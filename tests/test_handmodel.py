@@ -84,3 +84,39 @@ def test_fingers_curl_towards_palm():
     # index fingertip: palm spheres + thumb block + index bones
     tip = hm.NUM_PALM_SPHERES + 2 * spheres_per_finger - 1
     assert float(c_curl[tip, 2]) < float(c_open[tip, 2])
+
+
+def _two_hands():
+    left = hm.default_pose(0.5).at[0].set(-0.04).at[3:7].set(jnp.asarray([0.9, 0.1, 0.3, 0.0]))
+    right = hm.default_pose(0.62).at[0].set(0.04).at[7:].set(0.4)
+    return jnp.concatenate([left, right])
+
+
+def test_a_pose_of_two_hands_acts_hand_by_hand():
+    """Bounds, normalisation and spheres of a 54-wide pose are each hand's
+    own, the left hand's spheres mirrored in its local x."""
+    h = _two_hands()
+    left, right = h[:hm.NUM_PARAMS], h[hm.NUM_PARAMS:]
+    assert hm.num_hands(h) == 2 and len(hm.hands(h)) == 2
+    for fn in (hm.parameter_lower_bounds, hm.parameter_upper_bounds,
+               hm.normalize_configuration):
+        np.testing.assert_array_equal(
+            np.asarray(fn(h)), np.concatenate([np.asarray(fn(left)), np.asarray(fn(right))]))
+    batch = jnp.stack([h, 2.0 * h])
+    np.testing.assert_array_equal(np.asarray(hm.normalize_configuration(batch)[1]),
+                                  np.asarray(hm.normalize_configuration(2.0 * h)))
+    spheres = hm.pack_spheres(h)
+    assert spheres.shape == (2 * hm.NUM_SPHERES, 4)
+    np.testing.assert_array_equal(np.asarray(spheres[hm.NUM_SPHERES:]),
+                                  np.asarray(hm.pack_spheres(right)))
+    c, r = hm.hand_spheres_world(left, left=True)
+    np.testing.assert_array_equal(np.asarray(spheres[:hm.NUM_SPHERES]),
+                                  np.asarray(jnp.concatenate([c, r[:, None]], axis=-1)))
+    c_right, _ = hm.hand_spheres_world(left)
+    assert not np.allclose(np.asarray(c), np.asarray(c_right))
+
+
+@pytest.mark.parametrize("width", [26, 28, 81, 108])
+def test_a_pose_of_neither_one_nor_two_hands_is_refused(width):
+    with pytest.raises(ValueError, match="1 or 2 hands"):
+        hm.pack_spheres(jnp.zeros((width,)))

@@ -192,3 +192,64 @@ def test_step_writes_its_spans_when_the_profiler_traces(tmp_path):
     assert outer[3]["frame"] == 1
     for name, start, end, _ in spans:
         assert outer[1] <= start <= end <= outer[2], name
+
+
+# Four one-hand 32x32 frames tracked by the tree before poses of two
+# hands: each frame's score, and a sha256 over each pose's and score's
+# float32 bytes in turn. A pose of one hand takes the same path, to the bit.
+ONE_HAND_SCORES = [0.021686801686882973, 0.034813933074474335,
+                   0.05444156751036644, 0.05730573460459709]
+ONE_HAND_DIGEST = "4b729ddf97678a04183c1e67a06496192f6e9f5beae902bbd5398e011eaba004"
+
+
+def test_one_hand_frames_are_tracked_as_before():
+    import hashlib
+
+    cam = Camera(width=32, height=32, fx=27.5, fy=27.5, cx=15.5, cy=15.5)
+    frames, truth = rgbd.render_sequence(rgbd.SequenceConfig(
+        num_frames=5, camera=cam, noise_std=0.002, fast_burst=(100, 101)))
+    cfg = tracker.TrackerConfig(camera=cam, pso=pso.PSOConfig(num_particles=16,
+                                                              num_generations=4))
+    t = tracker.Tracker(cfg, h0=truth[0], seed=7)
+    digest, scores = hashlib.sha256(), []
+    for i in range(1, 5):
+        h, score = t.step(frames[i])
+        digest.update(np.asarray(h, np.float32).tobytes())
+        digest.update(np.float32(score).tobytes())
+        scores.append(float(np.float32(score)))
+    assert scores == ONE_HAND_SCORES
+    assert digest.hexdigest() == ONE_HAND_DIGEST
+
+
+def _compiled_text(h0):
+    cam = Camera(width=32, height=32, fx=28.0, fy=28.0, cx=15.5, cy=15.5)
+    cfg = dataclasses.replace(TINY_CFG, camera=cam)
+    return tracker.make_track_frame(cfg).lower(
+        jax.random.PRNGKey(0), h0, jnp.zeros((32, 32))).compile().as_text()
+
+
+def test_the_penalty_runs_under_collide_inside_evaluate_for_two_hands_only():
+    """With two hands, kc runs under ``tracker.collide``, nested in
+    ``tracker.evaluate`` (in spawn's evaluation and in the PSO loop's),
+    which ``bench.scopes`` charges it to; one hand has no such scope."""
+    from bench import scopes
+
+    two = _compiled_text(jnp.concatenate([hm.default_pose(), hm.default_pose()]))
+    collide = set(re.findall(r'op_name="([^"]*tracker\.collide[^"]*)"', two))
+    assert collide and all("tracker.evaluate/tracker.collide/" in n for n in collide)
+    assert any("tracker.spawn/" in n for n in collide)
+    assert any("tracker.optimize/" in n for n in collide)
+    assert "tracker.collide" in set(scopes.op_scopes(two).values())
+    assert "tracker.collide" not in _compiled_text(hm.default_pose())
+
+
+@pytest.mark.parametrize("hands", [1, 2])
+def test_the_staged_description_is_sized_by_the_hands(hands):
+    cfg = tracker.TrackerConfig(camera=CAM)
+    h0 = jnp.concatenate([hm.default_pose()] * hands)
+    one, comp = tracker.build_staged(cfg), tracker.build_staged(cfg, h0=h0)
+    h_next = {d.name: d.nbytes for s in comp.stages for d in s.outputs}["h_next"]
+    assert h_next == 4 * hands * hm.NUM_PARAMS
+    ratio = tracker._eval_flops_per_generation(cfg, hands) / tracker._eval_flops_per_generation(cfg)
+    assert ratio == pytest.approx(hands, rel=1e-2)  # but the ~3 operations a pixel
+    assert comp.total_flops() == pytest.approx(hands * one.total_flops(), rel=1e-2)
