@@ -13,9 +13,9 @@ offloadable unit (Multi-Step) or fused into one (Single-Step):
      apply temporal smoothing; emit h_{t+1}.
 
 The serial frame dependency (Fig. 3 category A) lives *outside* this
-module: ``track_frame`` maps (h_t, frame) -> h_{t+1}, and whoever drives
-it (examples/quickstart.py, sim/runtime.py) must wait for each frame's
-result before submitting the next.
+module: ``track_frame`` maps (key, h_t, frame) -> (key', h_{t+1}, score),
+and whoever drives it (``Tracker``, sim/runtime.py) feeds each frame's
+key and pose to the next.
 """
 
 from __future__ import annotations
@@ -134,20 +134,26 @@ def _track(cfg: TrackerConfig, key: jax.Array, h_prev: jnp.ndarray,
            depth: jnp.ndarray, wrap_eval: Callable[[pso.EvalFn], pso.EvalFn]):
     """One frame's four stages, each under its own ``jax.named_scope``
     (``tracker.preprocess`` ... ``tracker.refine``), so a device trace
-    reads by stage."""
+    reads by stage. The carried key is split here, on the device: the
+    frame draws from ``sub`` and hands ``key_next`` to the next frame,
+    the same stream as ``jax.random.split`` on the host."""
+    key_next, sub = jax.random.split(key)
     with jax.named_scope("tracker.preprocess"):
         d_o, mask = stage_preprocess(cfg, h_prev, depth)
     eval_fn = wrap_eval(_make_eval_fn(cfg, d_o, mask))
     with jax.named_scope("tracker.spawn"):
-        state, lo, hi = stage_spawn(cfg, key, h_prev, eval_fn)
+        state, lo, hi = stage_spawn(cfg, sub, h_prev, eval_fn)
     with jax.named_scope("tracker.optimize"):
         state = stage_optimize(cfg, state, lo, hi, eval_fn)
     with jax.named_scope("tracker.refine"):
-        return stage_refine(cfg, state, h_prev)
+        h_next, score = stage_refine(cfg, state, h_prev)
+    return key_next, h_next, score
 
 
 def make_track_frame(cfg: TrackerConfig) -> Callable:
-    """Build the jitted (key, h_prev, depth) -> (h_next, score) step."""
+    """Build the jitted (key, h_prev, depth) -> (key_next, h_next, score)
+    step: one device program a frame, which splits the carried key itself
+    and returns the key for the next frame beside the pose and score."""
 
     @jax.jit
     def track_frame(key: jax.Array, h_prev: jnp.ndarray, depth: jnp.ndarray):
@@ -158,7 +164,8 @@ def make_track_frame(cfg: TrackerConfig) -> Callable:
 
 def make_track_frame_sharded(cfg: TrackerConfig, mesh, axis: str = "model"):
     """Distributed variant: the particle population is sharded over a mesh
-    axis (the paper's GPGPU parallel axis mapped onto TPU devices)."""
+    axis (the paper's GPGPU parallel axis mapped onto TPU devices). The
+    same (key, h_prev, depth) -> (key_next, h_next, score) step."""
 
     @jax.jit
     def track_frame(key: jax.Array, h_prev: jnp.ndarray, depth: jnp.ndarray):
@@ -172,16 +179,17 @@ class StepPhases:
     """Where ``Tracker.step`` spends host time, always counted.
 
     Each step is a ``tracker.step`` profiler span that carries the frame
-    number, with one child span per phase (``tracker.key_split``,
-    ``tracker.dispatch``, ``tracker.wait``). The spans are written only
-    while ``jax.profiler`` traces, into the trace that holds the device's
+    number, with one child span per phase: ``tracker.dispatch``, the one
+    launch of the jitted step, and ``tracker.wait``, the one host wait
+    for the pose and score together. The spans are written only while
+    ``jax.profiler`` traces, into the trace that holds the device's
     operations. The counters cost one ``perf_counter`` read per phase:
     frames stepped, the host seconds of each phase summed over them, and
     the phase split of the slowest step, the first (compiling) step
     included; a caller that wants them from a later frame on replaces
     ``Tracker.phases`` with a new ``StepPhases``."""
 
-    PHASES = ("key_split", "dispatch", "wait")
+    PHASES = ("dispatch", "wait")
 
     def __init__(self):
         self.frames = 0
@@ -212,7 +220,11 @@ class StepPhases:
 
 
 class Tracker:
-    """Stateful convenience wrapper holding h_t across frames."""
+    """Stateful convenience wrapper holding h_t across frames.
+
+    After a step, ``key`` and ``h`` are the device arrays the step
+    returned, the inputs of the next: the PRNG key is carried on the
+    device and split inside the step, never on the host."""
 
     def __init__(self, cfg: TrackerConfig, h0: Optional[jnp.ndarray] = None,
                  seed: int = 0):
@@ -222,15 +234,19 @@ class Tracker:
         self._step = make_track_frame(cfg)
         self.phases = StepPhases()
 
-    def step(self, depth: jnp.ndarray) -> Tuple[jnp.ndarray, float]:
+    def step(self, depth: jnp.ndarray) -> Tuple[jax.Array, float]:
+        """Track one frame: one launch of the jitted step, then one host
+        wait that fetches the pose and score together, under the spans
+        ``tracker.step`` > ``tracker.dispatch``, ``tracker.wait``. Returns
+        the pose, the device array ``h`` whose host copy that wait
+        already holds (``np.asarray`` of it copies nothing more off an
+        accelerator), and the score as a float."""
         with self.phases.step():
-            with self.phases.phase("key_split"):
-                self.key, sub = jax.random.split(self.key)
             with self.phases.phase("dispatch"):
-                self.h, score = self._step(sub, self.h, depth)
+                self.key, self.h, score = self._step(self.key, self.h, depth)
             with self.phases.phase("wait"):
-                score = float(score)
-        return self.h, score
+                _, score = jax.device_get((self.h, score))
+        return self.h, float(score)
 
 
 # ---------------------------------------------------------------------------

@@ -138,8 +138,7 @@ def executed_run(
     ang_errs: List[float] = []
     lost = 0
     for ev in stats.processed:
-        key, sub = jax.random.split(key)
-        h, _ = step(sub, h, depth_frames[ev.index])
+        key, h, _ = step(key, h, depth_frames[ev.index])
         gt = truth[ev.index]
         pe = float(jnp.linalg.norm(h[:3] - gt[:3]))
         ae = float(jnp.mean(jnp.abs(h[7:] - gt[7:])))

@@ -91,6 +91,6 @@ def test_tracker_kernel_path_matches_reference_path():
     for use_kernel in (False, True):
         cfg = tracker.TrackerConfig(use_kernel=use_kernel, **base)
         step = tracker.make_track_frame(cfg)
-        h1, score = step(key, h0.at[0].add(0.02), depth)
-        assert h1.shape == (27,)
+        key1, h1, score = step(key, h0.at[0].add(0.02), depth)
+        assert h1.shape == (27,) and key1.shape == key.shape
         assert not bool(jnp.isnan(score))

@@ -58,8 +58,10 @@ with mesh:
 # particles are sharded -> the swarm argmin/gather needs collectives
 has_coll = any(k in txt for k in ("all-gather", "all-reduce", "collective-permute", "all-to-all"))
 print("LOWERED_OK collectives=%s" % has_coll)
-h1, score = step(key, h0.at[0].add(0.02), depth)
+key1, h1, score = step(key, h0.at[0].add(0.02), depth)
 assert h1.shape == (27,) and not bool(jnp.isnan(score))
+# the step splits the carried key itself, as the one-device step does
+np.testing.assert_array_equal(np.asarray(key1), np.asarray(jax.random.split(key)[0]))
 print("EXECUTED_OK")
 """
 

@@ -51,11 +51,12 @@ def test_stage_composition_matches_fused(short_sequence):
     h_prev = truth[0]
     depth = frames[1]
     fused = tracker.make_track_frame(cfg)
-    h_fused, score_fused = fused(key, h_prev, depth)
+    _, h_fused, score_fused = fused(key, h_prev, depth)
 
+    _, sub = jax.random.split(key)  # the fused step draws from the split key
     d_o, mask = tracker.stage_preprocess(cfg, h_prev, depth)
     eval_fn = tracker._make_eval_fn(cfg, d_o, mask)
-    state, lo, hi = tracker.stage_spawn(cfg, key, h_prev, eval_fn)
+    state, lo, hi = tracker.stage_spawn(cfg, sub, h_prev, eval_fn)
     state = tracker.stage_optimize(cfg, state, lo, hi, eval_fn)
     h_multi, score_multi = tracker.stage_refine(cfg, state, h_prev)
     np.testing.assert_allclose(
@@ -192,6 +193,64 @@ def test_step_writes_its_spans_when_the_profiler_traces(tmp_path):
     assert outer[3]["frame"] == 1
     for name, start, end, _ in spans:
         assert outer[1] <= start <= end <= outer[2], name
+
+
+def test_the_tracker_carries_the_host_split_key_stream():
+    """The key the step hands on is the one ``jax.random.split`` gives on
+    the host, frame after frame, to the bit: the random stream is the
+    same wherever the split runs."""
+    t = tracker.Tracker(TINY_CFG, h0=hm.default_pose(), seed=11)
+    key = jax.random.PRNGKey(11)
+    for _ in range(6):
+        t.step(jnp.zeros((16, 16)))
+        key, _ = jax.random.split(key)
+        np.testing.assert_array_equal(np.asarray(t.key), np.asarray(key))
+
+
+def test_step_fetches_pose_and_score_in_one_wait(monkeypatch):
+    """One ``jax.device_get`` brings pose and score to the host together;
+    the step returns the device pose it fetched, which the next frame
+    takes, and the score as a float, and keeps the key on the device."""
+    fetched = []
+
+    def device_get(x):
+        fetched.append(x)
+        return real(x)
+
+    real = jax.device_get
+    t = tracker.Tracker(TINY_CFG, h0=hm.default_pose())
+    monkeypatch.setattr(jax, "device_get", device_get)
+    h, score = t.step(jnp.zeros((16, 16)))
+    assert len(fetched) == 1
+    pose, fetched_score = fetched[0]
+    assert pose is h is t.h and isinstance(h, jax.Array)
+    assert type(score) is float and score == float(fetched_score)
+    assert isinstance(t.key, jax.Array)
+    assert t.key.dtype == jnp.uint32 and t.key.shape == (2,)
+
+
+def test_a_warm_step_runs_the_frame_program_alone():
+    """After warm-up a step compiles nothing; with JAX's caches cleared,
+    the one program it compiles, so the one it dispatches, is the jitted
+    frame step: no eager key split or other op runs beside it."""
+    compiled = []
+
+    def listen(event, duration, **kwargs):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(kwargs.get("fun_name"))
+
+    t = tracker.Tracker(TINY_CFG, h0=hm.default_pose())
+    depth = jnp.zeros((16, 16))
+    t.step(depth)
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        t.step(depth)
+        assert compiled == []
+        jax.clear_caches()
+        t.step(depth)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiled == ["jit(track_frame)"]
 
 
 # Four one-hand 32x32 frames tracked by the tree before poses of two
